@@ -31,12 +31,16 @@ def measured() -> None:
         )
         emit(m.name, m.us_per_call, f"{m.tflops:.3f}TF/s")
 
-    # Pallas tiling sweep (interpret mode: correctness + traffic model)
+    # Pallas tiling sweep: compiled on TPU, interpret mode elsewhere
+    # (there it checks correctness + the traffic model, not speed)
+    interpret = jax.default_backend() != "tpu"
     for bm, bn, bk in ((128, 128, 128), (256, 256, 256)):
         a = jax.random.normal(jax.random.PRNGKey(0), (512, 512), jnp.float32)
         b = jax.random.normal(jax.random.PRNGKey(1), (512, 512), jnp.float32)
         m = measure(
-            lambda: blocked_matmul(a, b, bm=bm, bn=bn, bk=bk),
+            lambda: blocked_matmul(
+                a, b, bm=bm, bn=bn, bk=bk, interpret=interpret
+            ),
             name=f"pallas_gemm[512,bm{bm}]", flops=2 * 512**3, repeats=2,
         )
         t = traffic_model(512, 512, 512, bm, bn, bk, 4)

@@ -4,44 +4,50 @@ number of injection streams.
 Alps: one NIC per GH200, 4 per node — full node bandwidth needs 4 MPI
 processes.  TPU analogue: per-chip DCN injection; a pod's inter-pod
 bandwidth scales with how many chips participate in the cross-pod
-collective.  Measured: psum over the 'pod' axis of a (2,4) host-device
-mesh in a subprocess.  Analytic: alpha-beta model over message size for
+collective.  Measured: psum over the 'pod' axis of a (2, n/2) mesh of the
+devices present, in this process (>= 2 needed).  Analytic: alpha-beta model over message size for
 1/2/4 streams."""
 
 from __future__ import annotations
 
-from benchmarks.common import emit, run_with_devices
-from repro.core import Link, get_active_system
+import time
 
-CODE = """
-import jax, jax.numpy as jnp, time
+import jax
+import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2, 4), ("pod", "data"))
-for log2 in (16, 20, 24):
-    n = 2 ** log2 // 4
-    x = jax.device_put(jnp.ones((n,), jnp.float32),
-                       NamedSharding(mesh, P()))
-    f = jax.jit(lambda v: v * 2, donate_argnums=0)  # warm baseline
-    # cross-pod all-reduce via psum under shard_map
-    from jax.experimental.shard_map import shard_map
-    g = jax.jit(shard_map(lambda v: jax.lax.psum(v, "pod"), mesh=mesh,
-                          in_specs=P(None), out_specs=P(None),
-                          check_rep=False))
-    out = g(x); jax.block_until_ready(out)
-    reps = 10
-    t0 = time.perf_counter()
-    for _ in range(reps):
+
+from benchmarks.common import emit, multi_device_count
+from repro.core import Link, get_active_system
+from repro.launch.mesh import make_mesh_for
+
+
+def measure_pod_reduce() -> None:
+    """Cross-'pod' psum over a (2, n/2) mesh of the devices present."""
+    n_dev = multi_device_count()
+    mesh = make_mesh_for((2, n_dev // 2), ("pod", "data"))
+    g = jax.jit(jax.shard_map(
+        lambda v: jax.lax.psum(v, "pod"), mesh=mesh,
+        in_specs=P(None), out_specs=P(None), check_vma=False,
+    ))
+    for log2 in (16, 20, 24):
+        n = 2 ** log2 // 4
+        x = jax.device_put(
+            jnp.ones((n,), jnp.float32), NamedSharding(mesh, P())
+        )
         out = g(x)
-    jax.block_until_ready(out)
-    dt = (time.perf_counter() - t0) / reps
-    gbps = (n * 4) / dt / 1e9
-    print(f"measured_podreduce[{n*4}B],{dt*1e6:.2f},{gbps:.2f}GB/s")
-"""
+        jax.block_until_ready(out)
+        reps = 10
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            out = g(x)
+        jax.block_until_ready(out)
+        dt = (time.perf_counter() - t0) / reps
+        emit(f"measured_podreduce[{n*4}B]", dt * 1e6,
+             f"{(n * 4) / dt / 1e9:.2f}GB/s")
 
 
 def main() -> None:
-    print(run_with_devices(CODE).strip())
+    measure_pod_reduce()
     sys = get_active_system()
     beta = sys.link_bandwidth(Link.DCN)
     alpha = sys.link_latency(Link.DCN)

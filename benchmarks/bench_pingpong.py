@@ -3,40 +3,48 @@
 TPU adaptation (DESIGN.md §2.1): the CAS ping-pong becomes a
 ``collective_permute`` round trip between mesh neighbors at increasing
 topological distance — the quantity preserved is which hop dominates
-small-message latency.  Measured on 8 host devices in a subprocess;
-analytic rows give the ICI-hop/DCN ladder of the hardware model."""
+small-message latency.  Measured on the devices present, in this process
+(>= 2 needed); analytic rows give the ICI-hop/DCN ladder of the hardware model."""
 
 from __future__ import annotations
 
-from benchmarks.common import emit, run_with_devices
-from repro.core import Link, get_active_system
+import time
 
-CODE = """
-import jax, jax.numpy as jnp, time
-from jax.experimental.shard_map import shard_map
+import jax
+import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((8,), ("x",))
-x = jnp.arange(8.0).reshape(8, 1)
-# single permute per dispatch (the two-permute program deadlocks the CPU
-# backend's transfer manager); round trip = 2x one-way.
-for dist in (1, 2, 4):
-    fwd = [(i, (i + dist) % 8) for i in range(8)]
-    f = jax.jit(shard_map(lambda v: jax.lax.ppermute(v, "x", fwd),
-                          mesh=mesh, in_specs=P("x"), out_specs=P("x")))
-    out = f(x); jax.block_until_ready(out)
-    n = 30
-    t0 = time.perf_counter()
-    for _ in range(n):
-        out = f(out)
-    jax.block_until_ready(out)
-    dt = 2 * (time.perf_counter() - t0) / n
-    print(f"pingpong[dist={dist}],{dt*1e6:.2f},round-trip(2x one-way)")
-"""
+
+from benchmarks.common import emit, multi_device_count
+from repro.core import Link, get_active_system
+from repro.launch.mesh import make_mesh_for
+
+
+def measure_pingpong() -> None:
+    """ppermute round trips on a ring of the devices present."""
+    n = multi_device_count()
+    mesh = make_mesh_for((n,), ("x",))
+    x = jnp.arange(float(n)).reshape(n, 1)
+    # single permute per dispatch (the two-permute program deadlocks the
+    # CPU backend's transfer manager); round trip = 2x one-way.
+    for dist in (d for d in (1, 2, 4) if d < n):
+        fwd = [(i, (i + dist) % n) for i in range(n)]
+        f = jax.jit(jax.shard_map(
+            lambda v, fwd=fwd: jax.lax.ppermute(v, "x", fwd),
+            mesh=mesh, in_specs=P("x"), out_specs=P("x"),
+        ))
+        out = f(x)
+        jax.block_until_ready(out)
+        reps = 30
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            out = f(out)
+        jax.block_until_ready(out)
+        dt = 2 * (time.perf_counter() - t0) / reps
+        emit(f"pingpong[dist={dist}]", dt * 1e6, "round-trip(2x one-way)")
 
 
 def main() -> None:
-    print(run_with_devices(CODE).strip())
+    measure_pingpong()
     # analytic ladder: 1 ICI hop, multi-hop, cross-pod (paper's G0/H0..H3)
     c = get_active_system()
     for hops in (1, 2, 4, 8):

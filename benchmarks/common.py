@@ -1,4 +1,4 @@
-"""Shared benchmark infra: CSV emission + subprocess multi-device runner.
+"""Shared benchmark infra: CSV emission + the multi-device guard.
 
 Every benchmark prints ``name,us_per_call,derived`` rows (one per measured
 or derived point).  Measured rows run on the available devices (CPU here);
@@ -7,12 +7,6 @@ hardware-adaptation note in DESIGN.md §2.1 prescribes.
 """
 
 from __future__ import annotations
-
-import os
-import subprocess
-import sys
-
-SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
 
 
 def emit(name: str, us_per_call: float, derived: str) -> None:
@@ -23,24 +17,20 @@ def emit_measurement(m, derived: str | None = None) -> None:
     print(m.csv(derived))
 
 
-def run_with_devices(code: str, n: int = 8, timeout: int = 600) -> str:
-    """Run a snippet under n forced host devices; returns stdout.
+def multi_device_count(minimum: int = 2) -> int:
+    """Number of devices present; raises with fewer than ``minimum``.
 
-    Used by the collective/pingpong benches — the main process must keep
-    seeing 1 device (task requirement), so multi-device measurement always
-    happens in a child process.
+    The collective benches measure in this process, on the devices it
+    sees: a single-device run has nothing to measure.  On a CPU host,
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` provides eight
+    devices.
     """
-    script = (
-        "import os\n"
-        f'os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n}"\n'
-        + code
-    )
-    env = dict(os.environ)
-    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    r = subprocess.run(
-        [sys.executable, "-c", script],
-        capture_output=True, text=True, timeout=timeout, env=env,
-    )
-    if r.returncode != 0:
-        raise RuntimeError(f"subprocess failed:\n{r.stderr[-2000:]}")
-    return r.stdout
+    import jax
+
+    n = len(jax.devices())
+    if n < minimum:
+        raise RuntimeError(
+            f"this benchmark needs >= {minimum} devices, found {n} "
+            f"({jax.devices()[0].platform})"
+        )
+    return n

@@ -19,7 +19,6 @@ DESIGN.md §6):
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 import time
 import traceback
@@ -58,14 +57,11 @@ def main() -> None:
         "--calibration", default=None, metavar="PATH",
         help="activate a measurement-calibrated hardware model from this "
              "calibration.json (created by tools/calibrate.py) so every "
-             "analytic row reports both spec and calibrated bounds; "
-             "defaults to ./calibration.json when that file exists",
+             "analytic row reports both spec and calibrated bounds",
     )
     args = ap.parse_args()
 
     cal_path = args.calibration
-    if cal_path is None and os.path.exists("calibration.json"):
-        cal_path = "calibration.json"
     if cal_path is not None:
         from repro.core.calibration import Calibration
         from repro.core.hardware import set_active_system

@@ -33,7 +33,6 @@ calibration moved each policy's number.
 """
 
 import argparse
-import os
 import time
 
 from repro.api import SPEC_SYSTEM
@@ -231,13 +230,10 @@ def main() -> None:
     ap.add_argument("--calibration", default=None, metavar="PATH",
                     help="activate a calibration.json (tools/calibrate.py) "
                          "so predictions use measured constants and the "
-                         "table reports meas/spec AND meas/cal ratios; "
-                         "defaults to ./calibration.json when it exists")
+                         "table reports meas/spec AND meas/cal ratios")
     args = ap.parse_args()
 
     cal_path = args.calibration
-    if cal_path is None and os.path.exists("calibration.json"):
-        cal_path = "calibration.json"
     if cal_path:
         from repro.core.calibration import load_or_calibrate
 

@@ -62,6 +62,7 @@ from repro.core.placement import (
     parse_policy,
     parse_role,
     parse_tier,
+    put_donating,
     registered_policies,
     validate_policy_for_mesh,
 )
@@ -846,7 +847,7 @@ class Runtime:
                 fsdp_axes=fsdp_axes,
             )
             moved = jax.tree.map(
-                lambda x, s: jax.device_put(x, s, donate=donate),
+                lambda x, s: put_donating(x, s, donate),
                 tree, new_specs,
             )
         else:

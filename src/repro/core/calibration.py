@@ -205,15 +205,14 @@ def _sweep_permute(axis_name: str, mesh_shape: tuple[int, ...],
     ``axis_name``, measuring per-chip shard bytes through one link."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh_for
 
-    mesh = make_mesh_compat(mesh_shape, axis_names)
+    mesh = make_mesh_for(mesh_shape, axis_names)
     axis_size = dict(zip(axis_names, mesh_shape))[axis_name]
     perm = [(i, (i + 1) % axis_size) for i in range(axis_size)]
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         lambda v: jax.lax.ppermute(v, axis_name, perm),
         mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name),
     ))
@@ -234,15 +233,14 @@ def _measure_psum(mesh_shape: tuple[int, ...], axis_names: tuple[str, ...],
     """bench_collectives' psum kernel: replay-only observation."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh_for
 
-    mesh = make_mesh_compat(mesh_shape, axis_names)
-    f = jax.jit(shard_map(
+    mesh = make_mesh_for(mesh_shape, axis_names)
+    f = jax.jit(jax.shard_map(
         lambda v: jax.lax.psum(v, axis_name),
-        mesh=mesh, in_specs=P(None), out_specs=P(None), check_rep=False,
+        mesh=mesh, in_specs=P(None), out_specs=P(None), check_vma=False,
     ))
     x = jnp.ones((nbytes // 4,), jnp.float32)
     return measure(

@@ -274,15 +274,44 @@ def _term_field(term: str) -> str:
 #: Spec-sheet baseline system (every term provenance ``spec``).
 DEFAULT_SYSTEM = SystemSpec()
 
-#: The process-wide system consumers resolve through get_active_system().
-_ACTIVE_SYSTEM: SystemSpec = DEFAULT_SYSTEM
+#: Spec sheets by ``device_kind`` as JAX reports it.  A TPU kind missing
+#: here is an error, never priced off another chip's sheet.
+SYSTEMS_BY_DEVICE_KIND: dict[str, SystemSpec] = {
+    "TPU v5 lite": DEFAULT_SYSTEM,      # TPU v5e
+}
+
+#: The process-wide system consumers resolve through get_active_system();
+#: None until first asked, then the attached device's sheet.
+_ACTIVE_SYSTEM: SystemSpec | None = None
+
+
+def system_for_device(device) -> SystemSpec:
+    """The spec sheet of ``device``: by ``device_kind`` on TPU (an unknown
+    kind raises ``ValueError``).  Other platforms — the CPU the tests run
+    on — stand in for the v5e this repository targets, so they price
+    against its sheet."""
+    if device.platform != "tpu":
+        return DEFAULT_SYSTEM
+    try:
+        return SYSTEMS_BY_DEVICE_KIND[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no SystemSpec for TPU device_kind {device.device_kind!r}; "
+            f"known kinds: {sorted(SYSTEMS_BY_DEVICE_KIND)}"
+        ) from None
 
 
 def get_active_system() -> SystemSpec:
     """The system every pricing path uses when no explicit ``system=`` is
-    passed: the spec-sheet baseline until :func:`set_active_system`
-    installs a calibrated one (see :meth:`repro.api.Runtime.calibrate`
-    and the launchers' ``--calibration`` flag)."""
+    passed: the attached device's spec sheet (:func:`system_for_device`)
+    until :func:`set_active_system` installs a calibrated one (see
+    :meth:`repro.api.Runtime.calibrate` and the launchers'
+    ``--calibration`` flag)."""
+    global _ACTIVE_SYSTEM
+    if _ACTIVE_SYSTEM is None:
+        import jax
+
+        _ACTIVE_SYSTEM = system_for_device(jax.devices()[0])
     return _ACTIVE_SYSTEM
 
 
@@ -292,7 +321,7 @@ def set_active_system(system: SystemSpec) -> SystemSpec:
     global _ACTIVE_SYSTEM
     if not isinstance(system, SystemSpec):
         raise TypeError(f"expected SystemSpec, got {type(system).__name__}")
-    prev = _ACTIVE_SYSTEM
+    prev = get_active_system()
     _ACTIVE_SYSTEM = system
     return prev
 

@@ -18,10 +18,9 @@ Physical realization on the runtime: JAX exposes ``NamedSharding(mesh,
 spec, memory_kind=...)`` with kinds ``device`` (HBM), ``pinned_host`` and
 ``unpinned_host`` — the TPU analogue of the paper's Table II allocation
 APIs (``numa_alloc_onnode`` ≈ explicit memory_kind; first-touch ≈ default
-``device``).  Not every backend exposes every kind (the CPU backend of
-older jax exposes only ``unpinned_host``), so every kind the policy
-requests is passed through :func:`resolve_memory_kind`, which degrades
-gracefully to what the backend actually has.
+``device``).  Every kind the policy requests is passed through
+:func:`resolve_memory_kind`, which raises for a kind the backend lacks
+rather than placing the tensor somewhere else.
 
 Peer and remote tiers are **executable**, not analysis-only: they are
 realized on a *donor mesh axis* (see :mod:`repro.launch.mesh`).  A mesh
@@ -214,12 +213,9 @@ def validate_policy_for_mesh(policy: "PlacementPolicy", mesh) -> None:
 
 
 # ---------------------------------------------------------------------------
-# Backend memory-kind capability (API-drift + hardware-capability shim)
+# Backend memory-kind capability
 # ---------------------------------------------------------------------------
 
-# Successful probes are memoized; failures are NOT (a query racing backend
-# init — e.g. before jax.distributed.initialize — must not pin the
-# "no memory kinds" fallback for the process lifetime).
 _KINDS_CACHE: frozenset[str] | None = None
 _DEFAULT_KIND_CACHE: str | None = None
 
@@ -228,43 +224,33 @@ def available_memory_kinds() -> frozenset[str]:
     """Memory kinds the default backend's device 0 can address."""
     global _KINDS_CACHE
     if _KINDS_CACHE is None:
-        try:
-            _KINDS_CACHE = frozenset(
-                m.kind for m in jax.devices()[0].addressable_memories()
-            )
-        except Exception:
-            return frozenset()
+        _KINDS_CACHE = frozenset(
+            m.kind for m in jax.devices()[0].addressable_memories()
+        )
     return _KINDS_CACHE
 
 
-def default_memory_kind() -> str | None:
+def default_memory_kind() -> str:
     """The backend's default memory kind (``device`` on TPU)."""
     global _DEFAULT_KIND_CACHE
     if _DEFAULT_KIND_CACHE is None:
-        try:
-            _DEFAULT_KIND_CACHE = jax.devices()[0].default_memory().kind
-        except Exception:
-            return None
+        _DEFAULT_KIND_CACHE = jax.devices()[0].default_memory().kind
     return _DEFAULT_KIND_CACHE
 
 
 def resolve_memory_kind(kind: str | None) -> str | None:
-    """Map a requested memory kind onto what the backend exposes.
+    """Check a requested memory kind against what the backend exposes.
 
-    ``None`` means "backend default" and always works.  Unavailable kinds
-    degrade: ``pinned_host`` falls back to ``unpinned_host`` when only that
-    is exposed, and anything else falls back to the backend default — the
-    graceful path for CPU backends where host DRAM *is* device memory.
+    ``None`` means "backend default" and always works.  A kind the
+    backend lacks raises ``ValueError``: a host tier never lands silently
+    in device memory.
     """
-    if kind is None:
-        return None
-    kinds = available_memory_kinds()
-    if kind in kinds:
+    if kind is None or kind in available_memory_kinds():
         return kind
-    if kind == "pinned_host" and "unpinned_host" in kinds:
-        if default_memory_kind() != "unpinned_host":
-            return "unpinned_host"
-    return None
+    raise ValueError(
+        f"memory kind {kind!r} is not exposed by this backend "
+        f"({jax.devices()[0].platform}: {sorted(available_memory_kinds())})"
+    )
 
 
 def host_available() -> bool:
@@ -814,6 +800,20 @@ def __getattr__(name: str):
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
+def put_donating(x, sharding, donate: bool):
+    """``device_put`` that donates ``x`` only within one memory kind.
+
+    The runtime refuses donation across memory kinds (a host <-> device
+    move is a copy between two pools, never an alias), so a move that
+    changes kind keeps its source buffer alive until it is dropped.
+    """
+    src = getattr(x, "sharding", None)
+    same_kind = src is not None and (
+        src.memory_kind or default_memory_kind()
+    ) == (sharding.memory_kind or default_memory_kind())
+    return jax.device_put(x, sharding, donate=donate and same_kind)
+
+
 def _put_like(tree, mesh: Mesh, specs, role: Role, policy: PlacementPolicy,
               *, donate: bool = False):
     """device_put a pytree under the policy's placement for ``role``.
@@ -842,10 +842,10 @@ def _put_like(tree, mesh: Mesh, specs, role: Role, policy: PlacementPolicy,
                 spec, x.shape, mesh, donor,
                 prefer_stack=pl.strategy is Strategy.STREAM,
             )
-        return jax.device_put(
+        return put_donating(
             x,
             NamedSharding(mesh, spec, memory_kind=policy.memory_kind(role)),
-            donate=donate,
+            donate,
         )
 
     if isinstance(specs, PartitionSpec):

@@ -1,24 +1,11 @@
 """Pallas TPU kernels for the data-movement hot spots.
 
 Per-kernel modules hold ``pl.pallas_call`` + BlockSpec tiling; ``ref.py``
-holds the pure-jnp oracles; ``ops.py`` is the public jit-able API with
-backend dispatch.  Validated in interpret mode on CPU (tests/test_kernels).
+holds the pure-jnp oracles; ``ops.py`` is the public jit-able API, which
+runs the compiled kernels on TPU and the oracles on every other platform.
+The kernels are validated in interpret mode on CPU (tests/test_kernels)
+and compiled for a described TPU v5e (tests/test_tpu_compile).
 """
 
-from jax.experimental.pallas import tpu as _pltpu
-
-
-def tpu_compiler_params(**kwargs):
-    """Version-portable ``pallas_call`` compiler params.
-
-    The class was renamed ``TPUCompilerParams`` -> ``CompilerParams`` across
-    jax releases; resolve whichever this install provides.
-    """
-    cls = getattr(_pltpu, "CompilerParams", None) or _pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-
-# tpu_compiler_params must be bound before the kernel modules import it
-# back from this package (ops -> per-kernel modules -> here).
-from repro.kernels import ops  # noqa: E402,F401
-from repro.kernels.ref import NEG_INF  # noqa: E402,F401
+from repro.kernels import ops  # noqa: F401
+from repro.kernels.ref import NEG_INF  # noqa: F401

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 DEFAULT_BM = 256
 DEFAULT_BN = 256
@@ -53,7 +52,7 @@ def blocked_matmul(
     bn: int = DEFAULT_BN,
     bk: int = DEFAULT_BK,
     out_dtype=None,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     M, K = a.shape
     K2, N = b.shape
@@ -72,7 +71,7 @@ def blocked_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 from repro.kernels.ref import NEG_INF
 
 DEFAULT_BLOCK_K = 512
@@ -27,7 +26,7 @@ DEFAULT_BLOCK_K = 512
 
 def _decode_kernel(
     len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-    *, bk, scale,
+    *, bk, scale, ragged,
 ):
     kv_idx = pl.program_id(2)
 
@@ -45,6 +44,11 @@ def _decode_kernel(
         q = q_ref[0, 0].astype(jnp.float32) * scale      # (G, D)
         k = k_ref[0, 0].astype(jnp.float32)              # (bk, D)
         v = v_ref[0, 0].astype(jnp.float32)              # (bk, D)
+        if ragged:
+            # the last tile overhangs the cache: its rows past the end
+            # hold unspecified values, and 0 * NaN would poison the sum
+            row = k_lo + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+            v = jnp.where(row < length, v, 0.0)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -76,20 +80,23 @@ def flash_decode(
     *,
     scale: float | None = None,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     B, Hq, D = q.shape
     _, Hkv, Smax, _ = k_cache.shape
     G = Hq // Hkv
     bk = min(block_k, Smax)
-    assert Smax % bk == 0, (Smax, bk)
     scale = (D ** -0.5) if scale is None else scale
 
     qg = q.reshape(B, Hkv, G, D)
-    grid = (B, Hkv, Smax // bk)
+    # any cache length: a last tile that overhangs the cache is masked by
+    # position, so the cache is never padded (a copy per step)
+    grid = (B, Hkv, pl.cdiv(Smax, bk))
 
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, bk=bk, scale=scale),
+        functools.partial(
+            _decode_kernel, bk=bk, scale=scale, ragged=Smax % bk != 0
+        ),
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),       # lengths
@@ -104,7 +111,7 @@ def flash_decode(
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G, D), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -25,7 +25,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 from repro.kernels.ref import NEG_INF
 
 DEFAULT_BLOCK_Q = 128
@@ -58,7 +57,7 @@ def _block_reachable(kind: str, window: int, chunk: int,
 
 def _fa_kernel(
     q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-    *, bq, bk, scale, kind, window, chunk, q_offset,
+    *, bq, bk, scale, kind, window, chunk, q_offset, k_len,
 ):
     kv_idx = pl.program_id(2)
     q_idx = pl.program_id(1)
@@ -85,10 +84,9 @@ def _fa_kernel(
         )  # (bq, bk)
         q_pos = q_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        if kind == "bidirectional":
-            mask = jnp.ones((bq, bk), bool)
-        else:
-            mask = q_pos >= k_pos
+        mask = k_pos < k_len                 # keys past Sk are padding
+        if kind != "bidirectional":
+            mask &= q_pos >= k_pos
             if kind == "sliding":
                 mask &= (q_pos - k_pos) < window
             elif kind == "chunked":
@@ -124,15 +122,22 @@ def flash_attention(
     q_offset: int = 0,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Pallas flash attention. GQA handled by repeating KV heads blockwise."""
     B, Hq, Sq, D = q.shape
-    _, Hkv, Sk, _ = k.shape
+    _, Hkv, k_len, _ = k.shape
     G = Hq // Hkv
+    # lengths the blocks do not divide are padded up to a block multiple:
+    # padded queries are cut from the output, padded keys masked out
     bq = min(block_q, Sq)
-    bk = min(block_k, Sk)
-    assert Sq % bq == 0 and Sk % bk == 0, (Sq, bq, Sk, bk)
+    bk = min(block_k, k_len)
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, (-Sq) % bq), (0, 0)))
+    k, v = (
+        jnp.pad(t, ((0, 0), (0, 0), (0, (-k_len) % bk), (0, 0)))
+        for t in (k, v)
+    )
+    Sq_out, Sq, Sk = Sq, q.shape[2], k.shape[2]
     scale = (D ** -0.5) if scale is None else scale
 
     # collapse (B, Hq) into one parallel grid axis; map each q-head block
@@ -155,7 +160,7 @@ def flash_attention(
         functools.partial(
             _fa_kernel,
             bq=bq, bk=bk, scale=scale, kind=kind,
-            window=window, chunk=chunk, q_offset=q_offset,
+            window=window, chunk=chunk, q_offset=q_offset, k_len=k_len,
         ),
         grid=grid,
         in_specs=[
@@ -170,12 +175,12 @@ def flash_attention(
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(qf, kf, vf)
-    return out.reshape(B, Hq, Sq, D)
+    return out.reshape(B, Hq, Sq, D)[:, :, :Sq_out]
 
 
 def _prefill_kernel(
@@ -199,8 +204,8 @@ def _prefill_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    qp = qpos_ref[0]                                 # (bq,) int32
-    kp = kpos_ref[0]                                 # (bk,) int32
+    qp = qpos_ref[0, 0]                              # (bq,) int32
+    kp = kpos_ref[0, 0]                              # (bk,) int32
     mask = (qp[:, None] >= kp[None, :]) & (kp[None, :] >= 0)
     if kind == "sliding":
         mask &= (qp[:, None] - kp[None, :]) < window
@@ -247,7 +252,7 @@ def flash_prefill(
     scale: float | None = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Pallas chunked-prefill attention (ref: ``ref.prefill_attention``).
 
@@ -282,11 +287,14 @@ def flash_prefill(
     def kv_map(bh, i, j):
         return ((bh // Hq) * Hkv + (bh % Hq) // G, j, 0)
 
+    # positions ride as (B, 1, S): a (1, 1, block) tile's last two dims
+    # are (whole axis, lane multiple), which the TPU tiling rule admits
+    # for any batch — a (1, block) tile of (B, S) is refused for B > 1
     def qpos_map(bh, i, j):
-        return (bh // Hq, i)
+        return (bh // Hq, 0, i)
 
     def kpos_map(bh, i, j):
-        return (bh // Hq, j)
+        return (bh // Hq, 0, j)
 
     out = pl.pallas_call(
         functools.partial(
@@ -298,8 +306,8 @@ def flash_prefill(
             pl.BlockSpec((1, bq, D), q_map),
             pl.BlockSpec((1, bk, D), kv_map),
             pl.BlockSpec((1, bk, D), kv_map),
-            pl.BlockSpec((1, bq), qpos_map),
-            pl.BlockSpec((1, bk), kpos_map),
+            pl.BlockSpec((1, 1, bq), qpos_map),
+            pl.BlockSpec((1, 1, bk), kpos_map),
         ],
         out_specs=pl.BlockSpec((1, bq, D), q_map),
         out_shape=jax.ShapeDtypeStruct((B * Hq, Sq, D), q.dtype),
@@ -308,11 +316,15 @@ def flash_prefill(
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(qf, kf, vf, q_pos.astype(jnp.int32), k_pos.astype(jnp.int32))
+    )(
+        qf, kf, vf,
+        q_pos.astype(jnp.int32)[:, None, :],
+        k_pos.astype(jnp.int32)[:, None, :],
+    )
     return out.reshape(B, Hq, Sq, D)
 
 

@@ -1,15 +1,15 @@
 """Public jit'd wrappers over the Pallas kernels with ref dispatch.
 
-The model zoo calls these.  ``backend='ref'`` (default) runs the pure-jnp
-oracle — the path the multi-pod dry-run lowers (Pallas custom-calls carry
-no cost signal for the CPU-hosted roofline, and interpret mode is slow).
-``backend='pallas'`` runs the TPU-targeted kernels (interpret=True on CPU);
-tests sweep both and assert allclose.
+The model zoo calls these.  The platform picks the path: on TPU the
+compiled Pallas kernels run, on every other platform the pure-jnp
+oracles of ``ref.py`` do.  ``backend='pallas'`` / ``backend='ref'``
+forces one side; a forced Pallas call off the TPU runs the kernel in
+interpret mode, which is how the tests sweep both and assert allclose.
 
 Training gradients: when the Pallas forward is selected, attention ops are
 wrapped in ``jax.custom_vjp`` whose backward *recomputes* with the oracle —
 numerically exact, flash-style-memory only in forward.  (A Pallas backward
-kernel is a further optimization documented in EXPERIMENTS.md §Perf.)
+kernel is a further optimization.)
 """
 
 from __future__ import annotations
@@ -19,6 +19,7 @@ from typing import Literal
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import blocked_matmul as _bm
 from repro.kernels import decode_attention as _da
@@ -27,26 +28,77 @@ from repro.kernels import ref as _ref
 
 Backend = Literal["ref", "pallas"]
 
-_DEFAULT: Backend = "ref"
 
-
-def set_default_backend(backend: Backend) -> None:
-    global _DEFAULT
-    assert backend in ("ref", "pallas")
-    _DEFAULT = backend
-
-
-def get_default_backend() -> Backend:
-    return _DEFAULT
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
 def _resolve(backend: Backend | None) -> Backend:
-    return backend or _DEFAULT
+    """``backend`` when forced, else the platform's path."""
+    if backend is not None:
+        return backend
+    return "pallas" if _on_tpu() else "ref"
+
+
+def _split_axes(batch: int, heads: int, heads_rule: str, kv_cache: bool):
+    """Mesh axes a per-device kernel call splits its batch and head dims
+    over, laid out as the placement lays out a KV cache
+    (``defs_to_specs``): the rule table's ``batch`` and ``heads_rule``
+    axes, then, for a call that reads a peer/remote-tier cache, the
+    cache's donor axes on the first of the two dims they divide.
+    ``heads`` is the KV head count: q heads split by the same factor keep
+    each device's query groups beside their KV heads."""
+    from repro.models.sharding import (
+        current_kv_donor_axes,
+        current_mesh,
+        donor_extend,
+        spec_for,
+    )
+
+    mesh = current_mesh()
+    logical = ("batch", heads_rule)
+    spec = spec_for((batch, heads), logical, mesh)
+    donor = current_kv_donor_axes() if kv_cache else ()
+    if donor:
+        spec = donor_extend(spec, (batch, heads), mesh, donor, logical)
+    b, h = (*spec, None, None)[:2]
+    return {"b": b, "h": h, None: None}
+
+
+def _per_shard(fn, *args, dims, out_dims, heads, heads_rule="kv_heads",
+               kv_cache=False):
+    """``fn(*args)``, run per device of the active multi-device mesh.
+
+    XLA cannot partition a Mosaic kernel, so on a mesh of several devices
+    the call goes through ``shard_map``.  ``dims`` tags each argument's
+    dimensions (and ``out_dims`` the output's) ``"b"`` for batch, ``"h"``
+    for heads or None; tagged dims split over :func:`_split_axes`, the
+    rest arrive whole.  Attention and the SSD scan are independent per
+    batch row and per head, so no device needs another's slice.
+    """
+    from repro.models.sharding import current_mesh
+
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return fn(*args)
+    split = _split_axes(args[0].shape[0], heads, heads_rule, kv_cache)
+
+    def spec(tags):
+        return P(*(split[t] for t in tags))
+
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=tuple(spec(d) for d in dims), out_specs=spec(out_dims),
+        check_vma=False,
+    )(*args)
 
 
 # ---------------------------------------------------------------------------
 # attention
 # ---------------------------------------------------------------------------
+
+_ATTN = ("b", "h", None, None)     # (B, H, S, D)
+_SSD = ("b", None, "h", None)      # (B, T, H, P)
 
 @functools.partial(
     jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7)
@@ -54,7 +106,7 @@ def _resolve(backend: Backend | None) -> Backend:
 def _pallas_attention(q, k, v, kind, window, chunk, scale, q_offset):
     return _fa.flash_attention(
         q, k, v, kind=kind, window=window, chunk=chunk,
-        scale=scale, q_offset=q_offset,
+        scale=scale, q_offset=q_offset, interpret=not _on_tpu(),
     )
 
 
@@ -90,7 +142,12 @@ def attention(
 ):
     """(B, Hq, Sq, D) x (B, Hkv, Sk, D) GQA attention with mask kinds."""
     if _resolve(backend) == "pallas" and k_lengths is None:
-        return _pallas_attention(q, k, v, kind, window, chunk, scale, q_offset)
+        return _per_shard(
+            lambda q, k, v: _pallas_attention(
+                q, k, v, kind, window, chunk, scale, q_offset
+            ),
+            q, k, v, dims=(_ATTN,) * 3, out_dims=_ATTN, heads=k.shape[1],
+        )
     if k_lengths is None and q.shape[2] >= 2048:
         # long sequences: flash-style chunked evaluation (memory O(S·bq))
         return _ref.attention_chunked(
@@ -110,7 +167,14 @@ def decode_attention(
 ):
     """(B, Hq, D) single-token decode against a padded KV cache."""
     if _resolve(backend) == "pallas":
-        return _da.flash_decode(q, k_cache, v_cache, lengths, scale=scale)
+        return _per_shard(
+            lambda q, k, v, lens: _da.flash_decode(
+                q, k, v, lens, scale=scale, interpret=not _on_tpu()
+            ),
+            q, k_cache, v_cache, lengths,
+            dims=(("b", "h", None), _ATTN, _ATTN, ("b",)),
+            out_dims=("b", "h", None), heads=k_cache.shape[1], kv_cache=True,
+        )
     return _ref.decode_attention(q, k_cache, v_cache, lengths, scale=scale)
 
 
@@ -130,9 +194,14 @@ def prefill_attention(
     only — no VJP is registered for the Pallas path.
     """
     if _resolve(backend) == "pallas":
-        return _fa.flash_prefill(
+        return _per_shard(
+            lambda q, k, v, qp, kp: _fa.flash_prefill(
+                q, k, v, qp, kp, kind=kind, window=window, chunk=chunk,
+                scale=scale, interpret=not _on_tpu(),
+            ),
             q, k, v, q_pos, k_pos,
-            kind=kind, window=window, chunk=chunk, scale=scale,
+            dims=(_ATTN,) * 3 + (("b", None),) * 2, out_dims=_ATTN,
+            heads=k.shape[1], kv_cache=True,
         )
     return _ref.prefill_attention(
         q, k, v, q_pos, k_pos,
@@ -152,7 +221,7 @@ def _pallas_ssd(x, dt, A, Bmat, Cmat, chunk):
 def _ssd_pallas_fwd_only(x, dt, A, Bmat, Cmat, chunk):
     from repro.kernels.ssd_scan import ssd_scan as _k
 
-    return _k(x, dt, A, Bmat, Cmat, chunk=chunk)
+    return _k(x, dt, A, Bmat, Cmat, chunk=chunk, interpret=not _on_tpu())
 
 
 def _pallas_ssd_fwd(x, dt, A, Bmat, Cmat, chunk):
@@ -182,7 +251,12 @@ def ssd_scan(
         and init_state is None
         and not return_state
     ):
-        return _pallas_ssd(x, dt, A, Bmat, Cmat, chunk)
+        return _per_shard(
+            lambda x, dt, A, Bm, Cm: _pallas_ssd(x, dt, A, Bm, Cm, chunk),
+            x, dt, A, Bmat, Cmat,
+            dims=(_SSD, ("b", None, "h"), ("h",), ("b",), ("b",)),
+            out_dims=_SSD, heads=x.shape[2], heads_rule="ssm_heads",
+        )
     return _ref.ssd_scan(
         x, dt, A, Bmat, Cmat, chunk=chunk,
         init_state=init_state, return_state=return_state,
@@ -206,5 +280,8 @@ def matmul(
     backend: Backend | None = None,
 ):
     if _resolve(backend) == "pallas":
-        return _bm.blocked_matmul(a, b, bm=bm, bn=bn, bk=bk, out_dtype=out_dtype)
+        return _bm.blocked_matmul(
+            a, b, bm=bm, bn=bn, bk=bk, out_dtype=out_dtype,
+            interpret=not _on_tpu(),
+        )
     return _ref.matmul(a, b, out_dtype=out_dtype)

@@ -18,10 +18,9 @@ which is what makes ``kv_peer_hbm``/``weights_peer_hbm``/``opt_peer_host``
 :func:`make_donor_mesh`, or pass any shape containing the axis name to
 :func:`make_mesh_for`.
 
-All mesh construction goes through :func:`make_mesh_compat`, which papers
-over the ``jax.sharding.AxisType`` API drift: newer jax wants explicit
-``axis_types``; older installs (e.g. 0.4.x) have no such attribute and
-``jax.make_mesh`` rejects the kwarg.
+All mesh construction goes through :func:`make_mesh_for`, which builds
+``Auto`` axes: the sharding rules place tensors by constraint propagation,
+not by explicit-axis typing (``jax.make_mesh`` defaults to ``Explicit``).
 """
 
 from __future__ import annotations
@@ -31,30 +30,19 @@ import jax
 from repro.core.placement import DONOR_AXIS, REMOTE_DONOR_AXIS  # noqa: F401
 
 
-def _axis_types_kwargs(n_axes: int) -> dict:
-    """``axis_types=(Auto,)*n`` where supported, ``{}`` otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
-def make_mesh_compat(devices_shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Version-portable ``jax.make_mesh`` (omits axis_types when absent)."""
-    return jax.make_mesh(
-        devices_shape, axes, **_axis_types_kwargs(len(axes))
-    )
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh_for(shape, axes)
 
 
 def make_mesh_for(devices_shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh (tests, benchmarks, elastic rescale)."""
-    return make_mesh_compat(devices_shape, axes)
+    """Arbitrary mesh with ``Auto`` axes (tests, benchmarks, elastic
+    rescale)."""
+    return jax.make_mesh(
+        devices_shape, axes,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 def make_donor_mesh(
@@ -76,7 +64,7 @@ def make_donor_mesh(
     axis = REMOTE_DONOR_AXIS if remote else DONOR_AXIS
     if donor_size < 2:
         raise ValueError(f"donor axis needs >= 2 slices, got {donor_size}")
-    return make_mesh_compat(
+    return make_mesh_for(
         (donor_size, *compute_shape), (axis, *compute_axes)
     )
 

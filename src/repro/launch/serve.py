@@ -20,6 +20,7 @@ from repro.core.placement import (
     extract_pool_split,
     registered_policies,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh_for
 from repro.models.model_zoo import ModelBundle
 from repro.serve import (
@@ -99,6 +100,7 @@ def main() -> None:
     args = ap.parse_args()
 
     logging.basicConfig(level=logging.INFO, format="%(message)s")
+    enable_compile_cache()
     if args.calibration:
         from repro.core.calibration import load_or_calibrate
 

@@ -22,6 +22,7 @@ from repro.checkpoint import Checkpointer
 from repro.configs import get_config, smoke_config
 from repro.core.placement import registered_policies
 from repro.data import DataConfig, Prefetcher, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh_for
 from repro.models.model_zoo import ModelBundle
 from repro.optim.adamw import AdamWConfig
@@ -106,6 +107,7 @@ def main() -> None:
     args = ap.parse_args()
 
     logging.basicConfig(level=logging.INFO, format="%(message)s")
+    enable_compile_cache()
     if args.calibration:
         from repro.core.calibration import load_or_calibrate
 

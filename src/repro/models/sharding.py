@@ -54,16 +54,27 @@ class _Ctx(threading.local):
     def __init__(self):
         self.mesh: Mesh | None = None
         self.rules: dict[str, tuple[str, ...]] = dict(DEFAULT_RULES)
+        self.kv_donor_axes: tuple[str, ...] = ()
 
 
 _CTX = _Ctx()
 
 
 @contextlib.contextmanager
-def use_sharding(mesh: Mesh | None, rules: Mapping[str, Sequence[str]] | None = None):
-    """Install mesh + rules for trace-time constraint resolution."""
-    old_mesh, old_rules = _CTX.mesh, _CTX.rules
+def use_sharding(
+    mesh: Mesh | None,
+    rules: Mapping[str, Sequence[str]] | None = None,
+    kv_donor_axes: Sequence[str] = (),
+):
+    """Install mesh + rules for trace-time constraint resolution.
+
+    ``kv_donor_axes`` names the donor axes a peer/remote-tier KV cache is
+    sharded over (``donor_axes_for`` of its tier), so the per-device
+    attention kernels split the cache where it lives.
+    """
+    old = _CTX.mesh, _CTX.rules, _CTX.kv_donor_axes
     _CTX.mesh = mesh
+    _CTX.kv_donor_axes = tuple(kv_donor_axes)
     if rules is not None:
         merged = dict(DEFAULT_RULES)
         merged.update({
@@ -74,7 +85,7 @@ def use_sharding(mesh: Mesh | None, rules: Mapping[str, Sequence[str]] | None = 
     try:
         yield
     finally:
-        _CTX.mesh, _CTX.rules = old_mesh, old_rules
+        _CTX.mesh, _CTX.rules, _CTX.kv_donor_axes = old
 
 
 def current_mesh() -> Mesh | None:
@@ -83,6 +94,10 @@ def current_mesh() -> Mesh | None:
 
 def current_rules() -> dict[str, tuple[str, ...]]:
     return _CTX.rules
+
+
+def current_kv_donor_axes() -> tuple[str, ...]:
+    return _CTX.kv_donor_axes
 
 
 def spec_for(
@@ -470,7 +485,9 @@ def stack_defs(defs, count: int, axis_name: str | None = "layers"):
     """
     return jax.tree.map(
         lambda p: Param(
-            (count, *p.shape), (axis_name, *p.axes), p.init, p.scale,
+            (count, *p.shape), (axis_name, *p.axes), p.init,
+            # the fan-in is the layer's own, not the stack depth
+            p.scale if p.scale is not None else max(p.shape[0], 1) ** -0.5,
             p.dtype,
         ),
         defs,

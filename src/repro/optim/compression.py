@@ -20,11 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import (
-    axis_size_compat,
-    shard_map_compat,
-    shard_map_partial_ok,
-)
 
 
 def quantize(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -44,7 +39,7 @@ def quantized_all_reduce(x: jax.Array, axis_name: str) -> jax.Array:
 
     x is this shard's f32 gradient (replicated-layout w.r.t. the axis).
     """
-    n = axis_size_compat(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     orig_shape = x.shape
@@ -117,17 +112,13 @@ def compressed_grad_sync(
     spec = P()  # replicated over every axis; collectives only over `axis`
     specs_g = jax.tree.map(lambda _: spec, grads)
     specs_e = jax.tree.map(lambda _: spec, ef)
-    # Partial-manual shard_map (manual over `axis` only) miscompiles on old
-    # jax/XLA (spmd_partitioner manual-subgroup check failure); there, run
-    # fully manual — the P() specs replicate the grads first, which costs an
-    # all-gather over the non-pod axes but keeps identical numerics.
-    axis_names = {axis} if shard_map_partial_ok else None
-    fn_mapped = shard_map_compat(
+    # manual over `axis` only; the other mesh axes stay automatic
+    fn_mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(specs_g, specs_e),
         out_specs=(specs_g, specs_e),
-        axis_names=axis_names,
-        check=False,
+        axis_names={axis},
+        check_vma=False,
     )
     return fn_mapped(grads, ef)

@@ -60,12 +60,39 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.api import Runtime
 from repro.core.faults import TransientFault
-from repro.core.placement import Placement, PlacementPolicy, Role, parse_policy
+from repro.core.placement import (
+    Placement,
+    PlacementPolicy,
+    Role,
+    default_memory_kind,
+    donor_axes_for,
+    parse_policy,
+)
+from repro.models.sharding import use_sharding
 from repro.runtime.retry import MIGRATION_RETRY, retry_call
 from repro.serve import sampling as sampling_mod
 from repro.serve.state import idle_device_state, upload
 
 log = logging.getLogger("repro.serve.engine")
+
+
+def _device_twin(tree):
+    """Shardings of ``tree``'s leaves with host memory kinds swapped for
+    device memory, or None when every leaf already lives on the device."""
+    device = default_memory_kind()
+    shardings = jax.tree.map(lambda x: x.sharding, tree)
+    if all(
+        s.memory_kind in (None, device) for s in jax.tree.leaves(shardings)
+    ):
+        return None
+    return jax.tree.map(lambda s: s.with_memory_kind(device), shardings)
+
+
+def _mover(shardings):
+    """``device_put`` onto ``shardings`` (identity for None)."""
+    if shardings is None:
+        return lambda tree: tree
+    return lambda tree: jax.device_put(tree, shardings)
 
 
 class Executor:
@@ -124,6 +151,14 @@ class Executor:
                     f"shape {leaf.shape} with batch_slots="
                     f"{cfg.batch_slots}"
                 )
+        #: optional observer of each compiled step's (B, vocab) logits,
+        #: called as ``tap(step, logits, new_lens)`` after every
+        #: ``"decode"`` dispatch (``new_lens`` None) and every chunked
+        #: ``"prefill"`` dispatch (``new_lens`` (B,): the tokens each row
+        #: wrote; its logits are those of the last one), before the
+        #: scheduler's slot table advances.  Checks read served logits
+        #: through it; serving never sets it.
+        self.logits_tap = None
         #: phase counters (tokens and wall seconds) + lifecycle events
         self.counters = {
             "prefill_tokens": 0, "prefill_s": 0.0,
@@ -150,6 +185,14 @@ class Executor:
     @property
     def supports_chunked_prefill(self) -> bool:
         return self._prefill is not None
+
+    def hlo_text(self, step: str) -> str:
+        """Compiled HLO of the ``"decode"`` or ``"prefill"`` step — the
+        program each dispatch runs (e.g. whether it holds the Mosaic
+        kernels)."""
+        return {"decode": self._decode, "prefill": self._prefill}[
+            step
+        ].as_text()
 
     def _cache_defs(self):
         return self.bundle.cache_defs(self.cfg.batch_slots, self.cfg.max_len)
@@ -195,12 +238,32 @@ class Executor:
             self.policy.name,
         )
 
+        # Host-placed roles (kv_host, weights_stream) are computed on in
+        # device memory: the executor stages them in around each dispatch
+        # and writes the returned cache back to its tier.
+        params_dev = _device_twin(self.params)
+        caches_dev = _device_twin(self.caches)
+        self._params_in = _mover(params_dev)
+        self._caches_in = _mover(caches_dev)
+        self._repin = _mover(None if caches_dev is None else cache_specs)
+        cache_out = cache_specs if caches_dev is None else caches_dev
+
+        # the model traces under the executor's mesh and rules, so its
+        # sharding constraints and the per-device kernel calls
+        # (repro.kernels.ops) see the mesh the arrays live on, and split
+        # a donor-sharded KV cache where it lives
+        mesh, rules = self.mesh, cfg.rules
+        kv_donor = () if mesh is None else donor_axes_for(
+            mesh, self.policy.placement(Role.KV_CACHE).tier
+        )
+
         def _step_fn(p, state, caches):
-            logits, new_caches = bundle.decode_step(
-                p,
-                {"tokens": state["tokens"], "lengths": state["lengths"]},
-                caches,
-            )
+            with use_sharding(mesh, rules, kv_donor):
+                logits, new_caches = bundle.decode_step(
+                    p,
+                    {"tokens": state["tokens"], "lengths": state["lengths"]},
+                    caches,
+                )
             # the sampler layer, in-jit: greedy rows (temp == 0) take the
             # plain argmax — bit-identical to the pre-sampler engine
             next_tok = sampling_mod.sample_tokens(logits, state)    # (B,)
@@ -219,7 +282,8 @@ class Executor:
             out = jnp.stack(
                 [next_tok, (stopped & active).astype(jnp.int32)]
             )
-            return out, new_state, new_caches
+            # the logits stay on the device unless a logits_tap reads them
+            return out, new_state, new_caches, logits
 
         donate = (1, 2) if self._donate_cache else (1,)
         decode_jit = jax.jit(
@@ -235,7 +299,7 @@ class Executor:
             # with (place_state) or aliasing fails.
             out_shardings=(
                 None if cache_specs is None
-                else (None, self._state_sharding, cache_specs)
+                else (None, self._state_sharding, cache_out, None)
             ),
         )
         # Ahead-of-time: lower + compile against the live params/caches
@@ -248,7 +312,8 @@ class Executor:
             idle_device_state(cfg.batch_slots)
         )
         self._decode = decode_jit.lower(
-            self.params, self._proto_state, self.caches
+            self._params_in(self.params), self._proto_state,
+            self._caches_in(self.caches),
         ).compile()
 
         # offset-chunk prefill, probed by capability rather than family:
@@ -256,13 +321,15 @@ class Executor:
         # is read-only during generation); only a bundle whose
         # prefill_at raises NotImplementedError falls back to the
         # decode-step replay admission.
+        def _prefill_fn(p, batch, caches, offsets):
+            with use_sharding(mesh, rules, kv_donor):
+                return bundle.prefill_at(p, batch, caches, offsets)
+
         prefill_jit = jax.jit(
-            lambda p, batch, caches, offsets: bundle.prefill_at(
-                p, batch, caches, offsets
-            ),
+            _prefill_fn,
             donate_argnums=(2,) if self._donate_cache else (),
             out_shardings=(
-                None if cache_specs is None else (None, cache_specs)
+                None if cache_specs is None else (None, cache_out)
             ),
         )
         chunk = max(int(cfg.prefill_chunk), 1)
@@ -274,7 +341,8 @@ class Executor:
         proto_offsets = self.place_state(jnp.zeros((B,), jnp.int32))
         try:
             self._prefill = prefill_jit.lower(
-                self.params, proto_batch, self.caches, proto_offsets
+                self._params_in(self.params), proto_batch,
+                self._caches_in(self.caches), proto_offsets,
             ).compile()
         except NotImplementedError:
             self._prefill = None
@@ -298,7 +366,7 @@ class Executor:
                 caches, rows,
             ),
             donate_argnums=(0,) if self._donate_cache else (),
-            out_shardings=cache_specs,
+            out_shardings=cache_out,
         )
         self._audit_builds()
 
@@ -349,7 +417,7 @@ class Executor:
                 self.caches,
             )
             insert_compiled = self._insert.lower(
-                self.caches, proto_rows, jnp.int32(0)
+                self._caches_in(self.caches), proto_rows, jnp.int32(0)
             ).compile()
             self.audit_reports["insert"] = self.rt.audit(
                 insert_compiled, {"caches": Role.KV_CACHE},
@@ -382,9 +450,13 @@ class Executor:
         if self.rt.faults:
             self.rt.faults.check("decode")
         t0 = time.perf_counter()
-        out, new_state, self.caches = self._decode(
-            self.params, state, self.caches
+        out, new_state, caches, logits = self._decode(
+            self._params_in(self.params), state,
+            self._caches_in(self.caches),
         )
+        self.caches = self._repin(caches)
+        if self.logits_tap is not None:
+            self.logits_tap("decode", logits, None)
         copy_async = getattr(out, "copy_to_host_async", None)
         if copy_async is not None:
             copy_async()
@@ -456,8 +528,8 @@ class Executor:
                 if n > 0:
                     toks[i, :n] = prompt[lo : lo + n]
                     new_lens[i] = n
-            _, self.caches = self._prefill(
-                self.params,
+            logits, caches = self._prefill(
+                self._params_in(self.params),
                 # toks/new_lens are freshly built per chunk and never
                 # mutated after the handoff; lengths is a live mirror
                 # and goes through the race-safe upload copy.  place_state
@@ -467,9 +539,12 @@ class Executor:
                     "tokens": jnp.asarray(toks),
                     "new_lens": jnp.asarray(new_lens),
                 }),
-                self.caches,
+                self._caches_in(self.caches),
                 self.place_state(upload(table.lengths, np.int32)),
             )
+            self.caches = self._repin(caches)
+            if self.logits_tap is not None:
+                self.logits_tap("prefill", logits, new_lens)
             for i, _ in new:
                 table.lengths[i] += int(new_lens[i])
 
@@ -506,10 +581,12 @@ class Executor:
             for t in range(len(prompt) - 1):
                 toks = np.zeros((B, 1), np.int32)
                 toks[i, 0] = prompt[t]
-                _, _, self.caches = self._decode(
-                    self.params, self.place_state(idle_state(toks)),
-                    self.caches,
+                _, _, caches, _ = self._decode(
+                    self._params_in(self.params),
+                    self.place_state(idle_state(toks)),
+                    self._caches_in(self.caches),
                 )
+                self.caches = self._repin(caches)
                 table.lengths[i] += 1
 
     # -- preemption: slot spill / restore ---------------------------------
@@ -520,7 +597,7 @@ class Executor:
         if self.rt.faults:
             self.rt.faults.check("extract")
         t0 = time.perf_counter()
-        rows = self._extract(self.caches, jnp.int32(i))
+        rows = self._extract(self._caches_in(self.caches), jnp.int32(i))
         if self.mesh is not None:
             park = self.rt.policy.with_placement(Role.KV_CACHE, spill_to)
             rows = self.rt.realize(
@@ -533,9 +610,18 @@ class Executor:
     def insert_slot(self, i: int, rows) -> None:
         """Scatter parked rows back into slot ``i`` (promotion).  The
         insert jit donates the cache like the decode step and keeps the
-        pinned placement, so the move is bit-preserving and in place."""
+        pinned placement, so the move is bit-preserving and in place.
+        Rows parked in host DRAM are first brought to device memory: the
+        insert is one on-device update."""
         t0 = time.perf_counter()
-        self.caches = self._insert(self.caches, rows, jnp.int32(i))
+        device = default_memory_kind()
+        rows = jax.tree.map(
+            lambda r: jax.device_put(r, r.sharding.with_memory_kind(device)),
+            rows,
+        )
+        self.caches = self._repin(
+            self._insert(self._caches_in(self.caches), rows, jnp.int32(i))
+        )
         jax.block_until_ready(self.caches)
         self.counters["restore_s"] += time.perf_counter() - t0
 

@@ -21,7 +21,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map_compat, shard_map_partial_ok
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -96,18 +95,15 @@ def pipelined_forward(
     apply = pipeline_apply(stage_fn, axis_name, n_stages, n_micro)
 
     pspec = jax.tree.map(lambda _: P(axis_name), stacked_params)
-    # New API: axis_names={pipe axis} keeps the other mesh axes automatic,
-    # so stage bodies still run TP/DP via constraint propagation.  Old jax
-    # rejects partial-manual shard_map on multi-axis meshes — there, run
-    # fully manual: the P() specs replicate the microbatches over the
-    # non-pipe axes (no in-stage TP/DP, identical numerics).
-    fn = shard_map_compat(
+    # axis_names={pipe axis} keeps the other mesh axes automatic, so
+    # stage bodies still run TP/DP via constraint propagation
+    fn = jax.shard_map(
         apply,
         mesh=mesh,
         in_specs=(pspec, P()),
         out_specs=P(),
-        axis_names={axis_name} if shard_map_partial_ok else None,
-        check=False,
+        axis_names={axis_name},
+        check_vma=False,
     )
     outs = fn(stacked_params, x_micro)
     return outs

@@ -32,6 +32,129 @@ def run_with_devices(body: str, n: int = 8, timeout: int = 600):
     return r.stdout
 
 
+class TestPallasPerShard:
+    """On a multi-device mesh the Pallas kernels run per device under
+    ``shard_map`` (XLA cannot partition a Mosaic kernel), split over the
+    batch axes and over the head axes: the rule table's ``model`` axis and
+    the donor axis a peer-tier KV cache is sharded over.  The compiled
+    kernel calls move no cache bytes between devices."""
+
+    @pytest.mark.parametrize("shape,axes,kv_spec,donor", [
+        ((2,), ("data",), "P('data')", ()),
+        ((4, 2), ("donor", "data"), "P('data', 'donor')", ("donor",)),
+        ((2, 4), ("data", "model"), "P('data', 'model')", ()),
+        ((4, 1), ("donor", "data"), "P('data', 'donor')", ("donor",)),
+    ], ids=["pool-mesh", "donor-mesh", "tp-mesh", "kv-peer-mesh"])
+    def test_kernels_match_oracle_under_mesh(self, shape, axes, kv_spec,
+                                             donor):
+        run_with_devices(f"""
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.kernels import ops
+        from repro.launch.mesh import make_mesh_for
+        from repro.models.sharding import use_sharding
+        mesh = make_mesh_for({shape!r}, {axes!r})
+        kv = NamedSharding(mesh, {kv_spec})
+        rep = NamedSharding(mesh, P())
+        ks = jax.random.split(jax.random.PRNGKey(0), 8)
+        # GQA: 8 query heads over 4 KV heads
+        q = jax.random.normal(ks[0], (4, 8, 32))
+        kc = jax.device_put(jax.random.normal(ks[1], (4, 4, 256, 32)), kv)
+        vc = jax.device_put(jax.random.normal(ks[2], (4, 4, 256, 32)), kv)
+        lens = jnp.asarray([1, 100, 256, 37], jnp.int32)
+        qc = jax.random.normal(ks[3], (4, 8, 8, 32))
+        qpos = 40 + jnp.arange(8)[None].repeat(4, 0)
+        kpos = jnp.where(jnp.arange(256) < 40, jnp.arange(256), -1)
+        kpos = kpos[None].repeat(4, 0)
+        x = jax.random.normal(ks[4], (4, 64, 4, 16))
+        dt = jax.nn.softplus(jax.random.normal(ks[5], (4, 64, 4)))
+        a = -jnp.exp(jax.random.normal(ks[6], (4,)) * 0.5)
+        bm = jax.random.normal(ks[7], (4, 64, 16))
+        cm = jax.random.normal(ks[0], (4, 64, 16))
+
+        def dec(backend):
+            return jax.jit(lambda q, k, v, l: ops.decode_attention(
+                q, k, v, l, backend=backend))
+
+        def pre(backend):
+            return jax.jit(lambda q, k, v, a, b: ops.prefill_attention(
+                q, k, v, a, b, backend=backend))
+
+        def ssd(backend):
+            return jax.jit(lambda *xs: ops.ssd_scan(
+                *xs, chunk=32, backend=backend))
+
+        out = {{}}
+        for backend in ("pallas", "ref"):
+            with use_sharding(mesh, kv_donor_axes={donor!r}):
+                out[backend] = (
+                    dec(backend)(q, kc, vc, lens),
+                    pre(backend)(qc, kc, vc, qpos, kpos),
+                    ssd(backend)(x, dt, a, bm, cm),
+                )
+                if backend == "pallas":
+                    hlo = [
+                        dec(backend).lower(
+                            jax.device_put(q, rep), kc, vc,
+                            jax.device_put(lens, rep),
+                        ).compile().as_text(),
+                        pre(backend).lower(
+                            jax.device_put(qc, rep), kc, vc,
+                            jax.device_put(qpos, rep),
+                            jax.device_put(kpos, rep),
+                        ).compile().as_text(),
+                    ]
+        for got, want in zip(out["pallas"], out["ref"]):
+            np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
+        for text in hlo:
+            for op in ("all-gather", "all-to-all", "collective-permute"):
+                assert op not in text, op
+        print("OK")
+        """)
+
+
+    def test_kv_peer_server_splits_the_cache_where_it_lives(self):
+        run_with_devices("""
+        import re, jax, numpy as np
+        from repro.kernels import ops
+        from repro.launch.mesh import make_donor_mesh
+        from repro.models import get_smoke_bundle
+        from repro.serve import Request, ServeConfig, Server
+
+        # kv_peer_hbm's layout: one compute slice, the KV heads sharded
+        # over 4 donor slices
+        mesh = make_donor_mesh((1,), ("data",), 4)
+        b = get_smoke_bundle("olmo-1b")
+        params = b.init_params(jax.random.PRNGKey(0), "float32")
+
+        def serve():
+            srv = Server(b, ServeConfig(batch_slots=4, max_len=32,
+                                        policy="kv_peer_hbm"),
+                         params, mesh=mesh)
+            reqs = [Request(rid=i, max_new_tokens=4, prompt=np.arange(
+                        1 + i, 7 + 2 * i, dtype=np.int32)) for i in range(4)]
+            for r in reqs:
+                srv.add_request(r)
+            srv.run_until_done(200)
+            return srv, [list(r.out_tokens) for r in reqs]
+
+        _, want = serve()                    # jnp oracles, XLA-partitioned
+        ops._resolve = lambda backend: "pallas"  # per-device kernels
+        srv, got = serve()
+        assert got == want, (got, want)
+        # a per-layer cache slice holds 4*4*32*16 elements: no collective
+        # in the compiled steps gathers one
+        layer = 4 * 4 * 32 * 16
+        for step in ("decode", "prefill"):
+            hlo = srv.engine.hlo_text(step)
+            for m in re.finditer(r"=(.*?) all-gather(?:-start)?\\(", hlo):
+                for dims in re.findall(r"\\w+\\[([\\d,]*)\\]", m.group(1)):
+                    n = int(np.prod([int(d) for d in dims.split(",") if d]))
+                    assert n < layer, (step, m.group(0))
+        print("OK")
+        """)
+
+
 class TestQuantizedAllReduce:
     def test_matches_mean_within_quantization(self):
         run_with_devices("""
@@ -39,8 +162,8 @@ class TestQuantizedAllReduce:
         from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.optim.compression import quantized_all_reduce
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((8,), ("pod",))
+        from repro.launch.mesh import make_mesh_for
+        mesh = make_mesh_for((8,), ("pod",))
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
         f = shard_map(lambda v: quantized_all_reduce(v[0], "pod")[None],
                       mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
@@ -85,8 +208,8 @@ class TestPipelineParallel:
         run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.train.pipeline_parallel import pipelined_forward
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((4,), ("pod",))
+        from repro.launch.mesh import make_mesh_for
+        mesh = make_mesh_for((4,), ("pod",))
         n_stages, n_micro, B, D = 4, 8, 2, 16
         ws = jax.random.normal(jax.random.PRNGKey(0), (n_stages, D, D)) * 0.3
         xs = jax.random.normal(jax.random.PRNGKey(1), (n_micro, B, D))
@@ -108,8 +231,8 @@ class TestPipelineParallel:
         run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.train.pipeline_parallel import pipelined_forward
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2,), ("pod",))
+        from repro.launch.mesh import make_mesh_for
+        mesh = make_mesh_for((2,), ("pod",))
         ws = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 8)) * 0.3
         xs = jax.random.normal(jax.random.PRNGKey(1), (4, 2, 8))
         def stage_fn(w, x):
@@ -142,8 +265,8 @@ class TestParallelConsistency:
         from repro.data import DataConfig, SyntheticLM
 
         def run(mesh_dims, axes):
-            from repro.launch.mesh import make_mesh_compat
-            mesh = make_mesh_compat(mesh_dims, axes)
+            from repro.launch.mesh import make_mesh_for
+            mesh = make_mesh_for(mesh_dims, axes)
             b = get_smoke_bundle("granite-8b")
             tcfg = TrainConfig(remat="none",
                 optimizer=AdamWConfig(lr=1e-3, warmup_steps=1))
@@ -170,8 +293,8 @@ class TestParallelConsistency:
         from repro.train import TrainConfig, init_train_state, make_train_step
         from repro.optim import AdamWConfig
         from repro.data import DataConfig, SyntheticLM
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import make_mesh_for
+        mesh = make_mesh_for((2, 2, 2), ("pod", "data", "model"))
         b = get_smoke_bundle("olmo-1b")
         tcfg = TrainConfig(remat="none", compress_pod_grads=True,
             optimizer=AdamWConfig(lr=3e-3, warmup_steps=5, weight_decay=0.0))
@@ -348,8 +471,8 @@ class TestPlacementPolicies:
         from repro.train import TrainConfig, init_train_state, make_train_step
         from repro.optim import AdamWConfig
         from repro.data import DataConfig, SyntheticLM
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh_for
+        mesh = make_mesh_for((2, 2), ("data", "model"))
         b = get_smoke_bundle("yi-6b")
         from repro.train.train_step import make_state_specs, repin_opt_state
 

@@ -277,6 +277,55 @@ class TestChunkedPrefillEquivalence:
             assert req.done and req.out_tokens == want, req.rid
 
 
+class TestLogitsTap:
+    def test_tap_sees_the_logits_the_server_decodes_from(self):
+        """``Executor.logits_tap`` observes each compiled dispatch: the
+        greedy tokens are the argmax of the tapped decode logits, and the
+        last prefill chunk's logits are a direct prefill's."""
+        bundle = _bundle("olmo-1b")
+        params = bundle.init_params(jax.random.PRNGKey(0), "float32")
+        rng = np.random.default_rng(3)
+        prompts = [
+            rng.integers(1, bundle.cfg.vocab, n).astype(np.int32)
+            for n in (9, 6)
+        ]
+        server = Server(
+            bundle,
+            ServeConfig(batch_slots=2, max_len=64, prefill_chunk=4),
+            params,
+        )
+        table = server.table
+        seen = {0: {"prefill": [], "decode": []},
+                1: {"prefill": [], "decode": []}}
+
+        def tap(step, logits, new_lens):
+            for i, rid in enumerate(table.slots):
+                if rid is None or (step == "prefill" and not new_lens[i]):
+                    continue
+                seen[rid][step].append(np.asarray(logits[i]))
+
+        server.engine.logits_tap = tap
+        reqs = [
+            Request(rid=i, prompt=p, max_new_tokens=5)
+            for i, p in enumerate(prompts)
+        ]
+        server.add_requests(reqs)
+        server.run_until_done(max_steps=100)
+        for req, prompt in zip(reqs, prompts):
+            got = seen[req.rid]
+            assert len(got["prefill"]) == -(-(len(prompt) - 1) // 4)
+            assert [int(np.argmax(x)) for x in got["decode"]] == \
+                req.out_tokens
+            want, _ = bundle.prefill(
+                params, {"tokens": jnp.asarray(prompt[:-1])[None]},
+                bundle.init_cache(1, 64),
+            )
+            np.testing.assert_allclose(
+                got["prefill"][-1], np.asarray(want[0]),
+                rtol=1e-4, atol=1e-4,
+            )
+
+
 class TestPrefillAttentionKernel:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize(
