@@ -715,9 +715,7 @@ class Runtime:
         calibration observation: it updates the EWMA that
         :meth:`decode_step_seconds` (and through it
         :meth:`preemption_price` users like the scheduler's preemption
-        ledger) returns, and logs predicted-vs-measured into
-        :attr:`replay` so step-time drift shows up in the same report as
-        the link calibrations.  Returns the updated EWMA.
+        ledger) returns.  Returns the updated EWMA.
         """
         seconds = float(seconds)
         if seconds <= 0.0:
@@ -727,13 +725,6 @@ class Runtime:
         ewma = (seconds if prev is None
                 else _EWMA_OLD * prev + _EWMA_NEW * seconds)
         self._step_observed[key] = ewma
-        self.replay.record(
-            "decode_step",
-            f"decode[{self.policy.name},b{batch_slots},l{max_len}]",
-            self._analytic_step_seconds(batch_slots, max_len),
-            seconds,
-            source="executor",
-        )
         return ewma
 
     # -- calibration -------------------------------------------------------

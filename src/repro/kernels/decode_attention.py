@@ -111,6 +111,7 @@ def flash_decode(
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G, D), jnp.float32),
         ],
+        name="flash_decode",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),

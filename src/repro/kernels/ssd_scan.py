@@ -132,6 +132,7 @@ def ssd_scan(
         out_specs=pl.BlockSpec((1, 1, c, P), lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bsz, H, T, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
+        name="ssd_scan",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),

@@ -56,6 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import TraceAnnotation as span
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.api import Runtime
@@ -159,10 +160,17 @@ class Executor:
         #: scheduler's slot table advances.  Checks read served logits
         #: through it; serving never sets it.
         self.logits_tap = None
-        #: phase counters (tokens and wall seconds) + lifecycle events
+        #: phase counters (tokens and wall seconds) + lifecycle events.
+        #: ``decode_steps`` counts compiled decode dispatches (with the
+        #: scheduler's ``decode_tokens``, live slot-steps: occupancy);
+        #: ``prefill_dispatches`` chunked prefill dispatches, each
+        #: ``batch_slots x prefill_chunk`` token positions wide
+        #: (``prefill_slot_tokens``; with ``prefill_tokens``, the prompt
+        #: tokens written: padding)
         self.counters = {
             "prefill_tokens": 0, "prefill_s": 0.0,
-            "decode_tokens": 0, "decode_s": 0.0,
+            "prefill_dispatches": 0, "prefill_slot_tokens": 0,
+            "decode_tokens": 0, "decode_s": 0.0, "decode_steps": 0,
             "replans": 0, "migrations": 0,
             "decode_replay_prefills": 0,
             "spill_s": 0.0, "restore_s": 0.0,
@@ -450,30 +458,39 @@ class Executor:
         if self.rt.faults:
             self.rt.faults.check("decode")
         t0 = time.perf_counter()
-        out, new_state, caches, logits = self._decode(
-            self._params_in(self.params), state,
-            self._caches_in(self.caches),
-        )
-        self.caches = self._repin(caches)
-        if self.logits_tap is not None:
-            self.logits_tap("decode", logits, None)
-        copy_async = getattr(out, "copy_to_host_async", None)
-        if copy_async is not None:
-            copy_async()
-        # the sanctioned once-per-step fetch: the packed (2, B) vector
-        out_host = np.asarray(out)  # repro: lint-disable=blocking-transfer-in-hot-path
-        dt = time.perf_counter() - t0
-        self.counters["decode_s"] += dt
-        # each warm step becomes a calibration observation on the Runtime:
-        # it updates the measured EWMA behind rt.decode_step_seconds (the
-        # preemption ledger's wait side) and logs predicted-vs-measured
-        # into rt.replay.  The first step after a (re)build is
-        # compile-dominated and skipped.
-        self._steps_since_build += 1
-        if self._steps_since_build > 1:
-            self.rt.observe_decode_step(
-                self.cfg.batch_slots, self.cfg.max_len, dt
+        # profiler spans (no-ops unless a trace is recording): the
+        # enqueue of the compiled step, the tap, the blocking fetch, and
+        # the bookkeeping before the scheduler's next tick
+        with span("serve.executor.decode.dispatch") as sp:
+            if sp.is_enabled():
+                sp.set_metadata(step=self.counters["decode_steps"])
+            out, new_state, caches, logits = self._decode(
+                self._params_in(self.params), state,
+                self._caches_in(self.caches),
             )
+            self.caches = self._repin(caches)
+        if self.logits_tap is not None:
+            with span("serve.executor.decode.tap"):
+                self.logits_tap("decode", logits, None)
+        with span("serve.executor.decode.fetch"):
+            copy_async = getattr(out, "copy_to_host_async", None)
+            if copy_async is not None:
+                copy_async()
+            # the sanctioned once-per-step fetch: the packed (2, B) vector
+            out_host = np.asarray(out)  # repro: lint-disable=blocking-transfer-in-hot-path
+        dt = time.perf_counter() - t0
+        with span("serve.executor.decode.observe"):
+            self.counters["decode_s"] += dt
+            self.counters["decode_steps"] += 1
+            # each warm step updates the measured EWMA on the Runtime
+            # behind rt.decode_step_seconds (the preemption ledger's wait
+            # side).  The first step after a (re)build is
+            # compile-dominated and skipped.
+            self._steps_since_build += 1
+            if self._steps_since_build > 1:
+                self.rt.observe_decode_step(
+                    self.cfg.batch_slots, self.cfg.max_len, dt
+                )
         return out_host[0], out_host[1].astype(bool), new_state
 
     @property
@@ -499,16 +516,20 @@ class Executor:
         """
         if self.rt.faults:
             self.rt.faults.check("prefill")
-        t0 = time.perf_counter()
-        if self._prefill is None:
-            self._replay_prefill(new, table)
-        else:
-            self._chunked_prefill(new, table)
-        jax.block_until_ready(self.caches)
-        self.counters["prefill_tokens"] += sum(
-            len(prompt) - 1 for _, prompt in new
-        )
-        self.counters["prefill_s"] += time.perf_counter() - t0
+        with span("serve.executor.prefill") as sp:
+            if sp.is_enabled():
+                sp.set_metadata(rows=len(new))
+            t0 = time.perf_counter()
+            if self._prefill is None:
+                self._replay_prefill(new, table)
+            else:
+                self._chunked_prefill(new, table)
+            with span("serve.executor.prefill.wait"):
+                jax.block_until_ready(self.caches)
+            self.counters["prefill_tokens"] += sum(
+                len(prompt) - 1 for _, prompt in new
+            )
+            self.counters["prefill_s"] += time.perf_counter() - t0
 
     def _chunked_prefill(self, new, table) -> None:
         chunk = max(int(self.cfg.prefill_chunk), 1)
@@ -520,33 +541,38 @@ class Executor:
         # with nothing to write.
         max_len = max(max(lens.values()), 1)
         B = self.cfg.batch_slots
-        for lo in range(0, max_len, chunk):
-            toks = np.zeros((B, chunk), np.int32)
-            new_lens = np.zeros(B, np.int32)
-            for i, prompt in new:
-                n = int(np.clip(lens[i] - lo, 0, chunk))
-                if n > 0:
-                    toks[i, :n] = prompt[lo : lo + n]
-                    new_lens[i] = n
-            logits, caches = self._prefill(
-                self._params_in(self.params),
-                # toks/new_lens are freshly built per chunk and never
-                # mutated after the handoff; lengths is a live mirror
-                # and goes through the race-safe upload copy.  place_state
-                # commits them to the replicated sharding the AOT
-                # executable was lowered against.
-                self.place_state({
-                    "tokens": jnp.asarray(toks),
-                    "new_lens": jnp.asarray(new_lens),
-                }),
-                self._caches_in(self.caches),
-                self.place_state(upload(table.lengths, np.int32)),
-            )
-            self.caches = self._repin(caches)
-            if self.logits_tap is not None:
-                self.logits_tap("prefill", logits, new_lens)
-            for i, _ in new:
-                table.lengths[i] += int(new_lens[i])
+        for k, lo in enumerate(range(0, max_len, chunk)):
+            with span("serve.executor.prefill.chunk") as sp:
+                if sp.is_enabled():
+                    sp.set_metadata(chunk=k)
+                toks = np.zeros((B, chunk), np.int32)
+                new_lens = np.zeros(B, np.int32)
+                for i, prompt in new:
+                    n = int(np.clip(lens[i] - lo, 0, chunk))
+                    if n > 0:
+                        toks[i, :n] = prompt[lo : lo + n]
+                        new_lens[i] = n
+                logits, caches = self._prefill(
+                    self._params_in(self.params),
+                    # toks/new_lens are freshly built per chunk and never
+                    # mutated after the handoff; lengths is a live mirror
+                    # and goes through the race-safe upload copy.
+                    # place_state commits them to the replicated sharding
+                    # the AOT executable was lowered against.
+                    self.place_state({
+                        "tokens": jnp.asarray(toks),
+                        "new_lens": jnp.asarray(new_lens),
+                    }),
+                    self._caches_in(self.caches),
+                    self.place_state(upload(table.lengths, np.int32)),
+                )
+                self.caches = self._repin(caches)
+                self.counters["prefill_dispatches"] += 1
+                self.counters["prefill_slot_tokens"] += B * chunk
+                if self.logits_tap is not None:
+                    self.logits_tap("prefill", logits, new_lens)
+                for i, _ in new:
+                    table.lengths[i] += int(new_lens[i])
 
     def _replay_prefill(self, new, table) -> None:
         """Fallback admission for bundles whose ``prefill_at`` raises

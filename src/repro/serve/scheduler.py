@@ -49,6 +49,7 @@ import time
 from typing import Callable
 
 import numpy as np
+from jax.profiler import TraceAnnotation as span
 
 from repro.core.faults import (
     FaultKind,
@@ -454,7 +455,8 @@ class Server:
         (admission / free / spill / promote).  Steady-state decode never
         calls this: the state lives on device and the host mirror
         advances from the *returned* token vector."""
-        self._state = self.engine.place_state(self.table.device_state())
+        with span("serve.scheduler.sync"):
+            self._state = self.engine.place_state(self.table.device_state())
 
     def _free_slot(self, i: int) -> int | None:
         """The one place an *occupied* slot returns to the pool: clears
@@ -584,6 +586,12 @@ class Server:
                 self.table.active[i] = True
         if changed:
             self._sync_state()
+
+    def _admitted_this_tick(self) -> list[int]:
+        """rids claimed or promoted into a slot this tick."""
+        t = self.table
+        return [rid for rid, tick in zip(t.slots, t.claimed_tick)
+                if rid is not None and tick == self._tick]
 
     def _promote(self, i: int, spilled: SpilledSequence) -> None:
         """Scatter a spilled sequence's parked rows back into slot ``i``
@@ -801,17 +809,28 @@ class Server:
         breaches stall → retry → evacuate → :class:`ServeHangError`.
         """
         self._tick += 1
-        self._reap_cancelled_expired()
-        try:
-            return self._step_inner()
-        except TierLossError as e:
-            self._recover_tier_loss(e)
-            return 0
+        # profiler spans (no-ops unless a trace is recording) name each
+        # phase of the tick; the executor's own nest inside them
+        with span("serve.scheduler.step") as sp:
+            if sp.is_enabled():
+                sp.set_metadata(tick=self._tick)
+            with span("serve.scheduler.reap"):
+                self._reap_cancelled_expired()
+            try:
+                return self._step_inner()
+            except TierLossError as e:
+                self._recover_tier_loss(e)
+                return 0
 
     def _step_inner(self) -> int:
-        self._maybe_preempt()
-        self._admit()
-        self._maybe_auto_replan()
+        with span("serve.scheduler.preempt"):
+            self._maybe_preempt()
+        with span("serve.scheduler.admit") as sp:
+            self._admit()
+            if sp.is_enabled():
+                sp.set_metadata(rids=str(self._admitted_this_tick()))
+        with span("serve.scheduler.replan"):
+            self._maybe_auto_replan()
         active = self.table.active_slots()
         if not active:
             return 0
@@ -821,28 +840,32 @@ class Server:
         decode_dt = now() - t0
         self.engine.counters["decode_tokens"] += len(active)
         freed = False
-        for i in active:
-            req = self._requests[self.table.slots[i]]
-            # host numpy already (the engine's one sanctioned fetch)
-            tok = int(tokens[i])  # repro: lint-disable=blocking-transfer-in-hot-path
-            req.out_tokens.append(tok)
-            if req.first_token_s is None:
-                req.first_token_s = now()
-            self.table.advance(i, tok)
-            if (
-                bool(stopped[i])
-                or len(req.out_tokens) >= req.max_new_tokens
-                or self.table.lengths[i] >= self.cfg.max_len - 1
-            ):
-                req.done = True
-                req.finished_s = now()
-                self._free_slot(i)
-                freed = True
-            if req.on_token is not None:
-                req.on_token(req, tok)
+        with span("serve.scheduler.deliver") as sp:
+            if sp.is_enabled():
+                sp.set_metadata(live=len(active))
+            for i in active:
+                req = self._requests[self.table.slots[i]]
+                # host numpy already (the engine's one sanctioned fetch)
+                tok = int(tokens[i])  # repro: lint-disable=blocking-transfer-in-hot-path
+                req.out_tokens.append(tok)
+                if req.first_token_s is None:
+                    req.first_token_s = now()
+                self.table.advance(i, tok)
+                if (
+                    bool(stopped[i])
+                    or len(req.out_tokens) >= req.max_new_tokens
+                    or self.table.lengths[i] >= self.cfg.max_len - 1
+                ):
+                    req.done = True
+                    req.finished_s = now()
+                    self._free_slot(i)
+                    freed = True
+                if req.on_token is not None:
+                    req.on_token(req, tok)
         if freed:
             self._sync_state()
-            self._maybe_auto_replan()
+            with span("serve.scheduler.replan"):
+                self._maybe_auto_replan()
         # feed the watchdog the decode wall time (admission/compile
         # excluded — the first step after a jit build is compile-
         # dominated and skipped, same warm-up rule as the step EWMA)
