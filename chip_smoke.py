@@ -43,7 +43,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.configs import get_config  # noqa: E402
-from repro.kernels import decode_attention, flash_attention, ref, ssd_scan  # noqa: E402
+from repro.kernels import decode_attention, flash_attention, kv_write, ref, ssd_scan  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_donor_mesh  # noqa: E402
 from repro.models.model_zoo import ModelBundle  # noqa: E402
@@ -172,15 +172,20 @@ def phase_serve(bundle, params, requests):
                 "(tpu_custom_call)"
             )
     log("compiled decode and prefill steps hold tpu_custom_call")
+    copies = server.stats()["decode_cache_copies"]
+    log(f"compiled decode step: {copies} cache-sized copies")
+    if copies:
+        raise AssertionError("the decode step copies the KV cache")
     return server, tokens, served_logits
 
 
 def phase_kernels(seed: int) -> None:
     """Each compiled Pallas kernel, at the default matmul precision the
     server compiles at, against its jnp oracle at the highest precision:
-    at olmo-1b widths (ssd_scan at mamba2-780m's), plus a cache length
-    that leaves flash_decode a partial last tile and a prompt length that
-    leaves flash_attention a padded last block."""
+    at olmo-1b widths (ssd_scan at mamba2-780m's), plus cache lengths
+    that leave flash_decode (600, 1500) and kv_row_write (1500) a partial
+    last tile and a prompt length that leaves flash_attention a padded
+    last block."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 8)
     bf = jnp.bfloat16
 
@@ -200,15 +205,25 @@ def phase_kernels(seed: int) -> None:
 
     # bf16 outputs: the kernel and the oracle may round an output to
     # neighbouring bf16 values, one ulp apart: 2**-6 below magnitude 4
-    for smax in (2048, 600):
+    # the decode kernels read and write layer 1 of a 2-layer stacked cache
+    layer = jnp.int32(1)
+    for smax in (2048, 600, 1500):
         q = rnd(0, (8, 16, 128))
-        k, v = rnd(1, (8, 16, smax, 128)), rnd(2, (8, 16, smax, 128))
+        k, v = rnd(1, (2, 8, 16, smax, 128)), rnd(2, (2, 8, 16, smax, 128))
         lens = jnp.asarray(
             np.random.default_rng(seed).integers(1, smax + 1, 8), jnp.int32
         )
         agree(f"flash_decode[cache {smax}]",
-              jax.jit(decode_attention.flash_decode)(q, k, v, lens),
-              lambda: ref.decode_attention(q, k, v, lens), 2e-2)
+              jax.jit(decode_attention.flash_decode)(q, k, v, lens, layer),
+              lambda: ref.decode_attention(q, k[1], v[1], lens), 2e-2)
+        rows = rnd(3, (2, 8, 16, 128))
+        slot = lens % smax
+        got = jax.jit(kv_write.kv_row_write)(k, v, rows[0], rows[1], slot, layer)
+        bidx = jnp.arange(8)
+        agree(f"kv_row_write[cache {smax}]",
+              jnp.stack(got),
+              lambda: jnp.stack([k.at[1, bidx, :, slot].set(rows[0]),
+                                 v.at[1, bidx, :, slot].set(rows[1])]), 0.0)
     q = rnd(3, (8, 16, 32, 128))
     k, v = rnd(4, (8, 16, 2080, 128)), rnd(5, (8, 16, 2080, 128))
     rng = np.random.default_rng(seed)

@@ -21,7 +21,12 @@ the placement policy, it checks
   (``stray-host-transfer``);
 * **byte plan** — per-role parameter bytes vs the planner's
   ``bytes_per_role`` within tolerance (``byte-plan-mismatch``, warning:
-  planner estimates legitimately diverge from padded/sharded reality).
+  planner estimates legitimately diverge from padded/sharded reality);
+* **cache-sized copies** — a count, not a violation: instructions whose
+  result is a KV-cache leaf's shape or one layer of it and that copy or
+  slice it (``cache_sized_copies``).  Each moves a whole layer or stack
+  of the cache per dispatch; the decode step should hold none.  In-place
+  row updates are not counted.
 
 Violations carry the op, bytes, tier edge, and the planner term they
 break, so a CI failure reads like a planner line item, not a grep hit.
@@ -30,16 +35,21 @@ break, so a CI failure reads like a planner line item, not a grep hit.
 from __future__ import annotations
 
 import dataclasses
+import math
+import re
 from collections import defaultdict
-from typing import Any, Mapping
+from typing import Any, Iterable, Iterator, Mapping
 
 from repro.core.hlo_analysis import (
     AliasPair,
+    Computation,
+    HloAnalyzer,
+    Instruction,
     TransferStat,
-    analyze_hlo_text,
     entry_parameters,
     parse_input_output_alias,
 )
+from repro.core.placement import Role
 
 
 class DonationAliasError(RuntimeError):
@@ -116,6 +126,8 @@ class AuditReport:
     host_transfer_bytes: float
     donation_expected: int
     donation_materialized: int
+    #: copies, slices and update-slices of a whole cache leaf or layer
+    cache_sized_copies: int = 0
 
     @property
     def ok(self) -> bool:
@@ -137,6 +149,7 @@ class AuditReport:
             "donation_expected": self.donation_expected,
             "donation_materialized": self.donation_materialized,
             "donation_coverage": self.donation_coverage,
+            "cache_sized_copies": self.cache_sized_copies,
             "role_bytes": dict(self.role_bytes),
             "n_transfers": len(self.transfers),
             "n_aliases": len(self.aliases),
@@ -158,6 +171,88 @@ class AuditReport:
 
 
 # ---------------------------------------------------------------------------
+# Cache-sized copies
+# ---------------------------------------------------------------------------
+
+#: opcodes that materialise a second copy of what they read
+COPY_OPCODES = frozenset({"copy", "dynamic-slice"})
+
+#: instructions that name a buffer without producing one
+PLUMBING = frozenset({"parameter", "get-tuple-element", "tuple"})
+
+_CALLS_RE = re.compile(r"calls=%?([\w.\-]+)")
+
+
+def _fused(ins) -> str | None:
+    """The computation a fusion instruction calls (None for others)."""
+    if ins.opcode != "fusion":
+        return None
+    m = _CALLS_RE.search(ins.attrs)
+    return m.group(1) if m else None
+
+
+def cache_sized_instructions(
+    comps: Mapping[str, Computation],
+    cache_shapes: Iterable[tuple[str, tuple[int, ...]]],
+) -> Iterator[tuple[Instruction, int]]:
+    """Every buffer the module materialises whose shape is one of the
+    cache leaf shapes ``(dtype, dims)`` or one layer of one (``dims[1:]``,
+    or with a leading 1), with the element count of that leaf's layer.
+
+    Buffers are the results of instructions outside fused computations
+    (a fusion's own result stands for its body), apart from parameters
+    and tuple plumbing.  A kernel returning the caches it aliases returns
+    a tuple, so it is not one."""
+    layer = {}
+    for dtype, dims in cache_shapes:
+        n = math.prod(dims[1:])
+        for d in (dims, dims[1:], (1, *dims[1:])):
+            layer[(dtype, d)] = min(n, layer.get((dtype, d), n))
+    fused = {
+        f for c in comps.values() for i in c.instructions.values()
+        if (f := _fused(i))
+    }
+    for name, comp in comps.items():
+        if name in fused:
+            continue
+        for ins in comp.instructions.values():
+            if ins.opcode in PLUMBING or ins.type_str.startswith("("):
+                continue
+            out = ins.shapes
+            if len(out) == 1 and (out[0].dtype, out[0].dims) in layer:
+                yield ins, layer[(out[0].dtype, out[0].dims)]
+
+
+def _copies(comps: Mapping[str, Computation], ins, floor: int) -> bool:
+    """Whether ``ins`` copies or slices out at least ``floor`` elements,
+    itself or in its fused body."""
+    if ins.opcode in COPY_OPCODES:
+        out = ins.shapes
+        return len(out) == 1 and out[0].numel >= floor
+    f = _fused(ins)
+    return f in comps and any(
+        _copies(comps, i, floor) for i in comps[f].instructions.values()
+    )
+
+
+def count_cache_sized_copies(
+    comps: Mapping[str, Computation],
+    cache_shapes: Iterable[tuple[str, tuple[int, ...]]],
+) -> int:
+    """The :func:`cache_sized_instructions` that copy or dynamic-slice a
+    cache leaf or a layer of one, themselves or fused: each moves a whole
+    layer or stack of the cache per dispatch.  An update-slice or a
+    scatter is not counted: XLA writes it into the operand's buffer in
+    place, moving only the update.  A fusion around one counts only where
+    its body also copies a layer or more (XLA's copy-then-update when the
+    buffer cannot be reused)."""
+    return sum(
+        _copies(comps, ins, floor)
+        for ins, floor in cache_sized_instructions(comps, cache_shapes)
+    )
+
+
+# ---------------------------------------------------------------------------
 # The audit
 # ---------------------------------------------------------------------------
 
@@ -167,8 +262,9 @@ def audit_hlo_text(
     mesh_axes: Mapping[str, int] | None = None,
 ) -> AuditReport:
     """Audit one compiled module's text against ``expected``."""
-    cost = analyze_hlo_text(text, mesh_axes)
-    params = entry_parameters(text)
+    analyzer = HloAnalyzer(text, mesh_axes)
+    cost = analyzer.analyze()
+    params = entry_parameters(text, analyzer.comps)
     aliases = parse_input_output_alias(text)
     aliased_params = {a.param_number for a in aliases}
     violations: list[AuditViolation] = []
@@ -256,6 +352,13 @@ def audit_hlo_text(
                 ),
             ))
 
+    cache_roots = {
+        r.arg_root for r in expected.roles if r.role == Role.KV_CACHE.value
+    }
+    cache_shapes = {
+        (sh.dtype, sh.dims)
+        for p in params if p.arg_root in cache_roots for sh in p.shapes
+    }
     return AuditReport(
         label=expected.label,
         violations=violations,
@@ -265,6 +368,9 @@ def audit_hlo_text(
         host_transfer_bytes=host_bytes,
         donation_expected=donation_expected,
         donation_materialized=donation_materialized,
+        cache_sized_copies=count_cache_sized_copies(
+            analyzer.comps, cache_shapes
+        ),
     )
 
 

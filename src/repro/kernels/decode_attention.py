@@ -8,6 +8,10 @@ streams the cache through VMEM in ``block_k`` tiles, carrying the online
 softmax statistics in scratch, with all ``G = Hq/Hkv`` query heads of a KV
 head processed per tile (the KV tile is read ONCE for all of them — the
 kernel-level expression of the paper's "reads dominate" GEMM finding).
+
+The cache is the decode step's whole stacked buffer ``(layers, B, Hkv,
+Smax, D)``; the layer to read is scalar-prefetched into the K/V index
+maps, so no layer slice of the cache is ever materialised.
 """
 
 from __future__ import annotations
@@ -25,9 +29,10 @@ DEFAULT_BLOCK_K = 512
 
 
 def _decode_kernel(
-    len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+    len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     *, bk, scale, ragged,
 ):
+    del layer_ref  # used by the index maps only
     kv_idx = pl.program_id(2)
 
     @pl.when(kv_idx == 0)
@@ -42,8 +47,8 @@ def _decode_kernel(
     @pl.when(k_lo < length)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale      # (G, D)
-        k = k_ref[0, 0].astype(jnp.float32)              # (bk, D)
-        v = v_ref[0, 0].astype(jnp.float32)              # (bk, D)
+        k = k_ref[0, 0, 0].astype(jnp.float32)           # (bk, D)
+        v = v_ref[0, 0, 0].astype(jnp.float32)           # (bk, D)
         if ragged:
             # the last tile overhangs the cache: its rows past the end
             # hold unspecified values, and 0 * NaN would poison the sum
@@ -74,16 +79,17 @@ def _decode_kernel(
 
 def flash_decode(
     q: jax.Array,        # (B, Hq, D) — one new token per row
-    k_cache: jax.Array,  # (B, Hkv, Smax, D)
-    v_cache: jax.Array,  # (B, Hkv, Smax, D)
+    k_cache: jax.Array,  # (L, B, Hkv, Smax, D) — the stacked cache
+    v_cache: jax.Array,  # (L, B, Hkv, Smax, D)
     lengths: jax.Array,  # (B,) int32 valid lengths
+    layer: jax.Array,    # () int32 layer of the stack to read
     *,
     scale: float | None = None,
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,
 ) -> jax.Array:
     B, Hq, D = q.shape
-    _, Hkv, Smax, _ = k_cache.shape
+    _, _, Hkv, Smax, _ = k_cache.shape
     G = Hq // Hkv
     bk = min(block_k, Smax)
     scale = (D ** -0.5) if scale is None else scale
@@ -93,28 +99,40 @@ def flash_decode(
     # position, so the cache is never padded (a copy per step)
     grid = (B, Hkv, pl.cdiv(Smax, bk))
 
+    def kv_map(b, h, j, len_ref, layer_ref):
+        return (layer_ref[0], b, h, j, 0)
+
+    def q_map(b, h, j, len_ref, layer_ref):
+        return (b, h, 0, 0)
+
     out = pl.pallas_call(
         functools.partial(
             _decode_kernel, bk=bk, scale=scale, ragged=Smax % bk != 0
         ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),       # lengths
-            pl.BlockSpec((1, 1, G, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, j: (b, h, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, j: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,                       # lengths, layer
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 1, G, D), q_map),
+                pl.BlockSpec((1, 1, 1, bk, D), kv_map),
+                pl.BlockSpec((1, 1, 1, bk, D), kv_map),
+            ],
+            out_specs=pl.BlockSpec((1, 1, G, D), q_map),
+            scratch_shapes=[
+                pltpu.VMEM((G,), jnp.float32),
+                pltpu.VMEM((G,), jnp.float32),
+                pltpu.VMEM((G, D), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
         name="flash_decode",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(lengths, qg, k_cache, v_cache)
+    )(
+        lengths.astype(jnp.int32),
+        jnp.reshape(layer, (1,)).astype(jnp.int32),
+        qg, k_cache, v_cache,
+    )
     return out.reshape(B, Hq, D)

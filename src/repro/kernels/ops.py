@@ -24,6 +24,7 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels import blocked_matmul as _bm
 from repro.kernels import decode_attention as _da
 from repro.kernels import flash_attention as _fa
+from repro.kernels import kv_write as _kw
 from repro.kernels import ref as _ref
 
 Backend = Literal["ref", "pallas"]
@@ -86,9 +87,13 @@ def _per_shard(fn, *args, dims, out_dims, heads, heads_rule="kv_heads",
     def spec(tags):
         return P(*(split[t] for t in tags))
 
+    out_specs = (
+        tuple(spec(d) for d in out_dims) if isinstance(out_dims[0], tuple)
+        else spec(out_dims)
+    )
     return jax.shard_map(
         fn, mesh=mesh,
-        in_specs=tuple(spec(d) for d in dims), out_specs=spec(out_dims),
+        in_specs=tuple(spec(d) for d in dims), out_specs=out_specs,
         check_vma=False,
     )(*args)
 
@@ -98,6 +103,7 @@ def _per_shard(fn, *args, dims, out_dims, heads, heads_rule="kv_heads",
 # ---------------------------------------------------------------------------
 
 _ATTN = ("b", "h", None, None)     # (B, H, S, D)
+_STACK = (None, "b", "h", None, None)   # (layers, B, Hkv, S, D)
 _SSD = ("b", None, "h", None)      # (B, T, H, P)
 
 @functools.partial(
@@ -161,21 +167,57 @@ def attention(
 
 
 def decode_attention(
-    q, k_cache, v_cache, lengths, *,
+    q, k_cache, v_cache, lengths, layer, *,
     scale: float | None = None,
     backend: Backend | None = None,
 ):
-    """(B, Hq, D) single-token decode against a padded KV cache."""
+    """(B, Hq, D) single-token decode against layer ``layer`` of a
+    stacked (L, B, Hkv, S, D) KV cache."""
     if _resolve(backend) == "pallas":
         return _per_shard(
-            lambda q, k, v, lens: _da.flash_decode(
-                q, k, v, lens, scale=scale, interpret=not _on_tpu()
+            lambda q, k, v, lens, i: _da.flash_decode(
+                q, k, v, lens, i, scale=scale, interpret=not _on_tpu()
             ),
-            q, k_cache, v_cache, lengths,
-            dims=(("b", "h", None), _ATTN, _ATTN, ("b",)),
-            out_dims=("b", "h", None), heads=k_cache.shape[1], kv_cache=True,
+            q, k_cache, v_cache, lengths, layer,
+            dims=(("b", "h", None), _STACK, _STACK, ("b",), ()),
+            out_dims=("b", "h", None), heads=k_cache.shape[2], kv_cache=True,
         )
-    return _ref.decode_attention(q, k_cache, v_cache, lengths, scale=scale)
+    return _ref.decode_attention(
+        q,
+        jax.lax.dynamic_index_in_dim(k_cache, layer, keepdims=False),
+        jax.lax.dynamic_index_in_dim(v_cache, layer, keepdims=False),
+        lengths, scale=scale,
+    )
+
+
+def kv_row_write(
+    k_cache, v_cache, k_row, v_row, slot, layer, *,
+    backend: Backend | None = None,
+):
+    """Write each row's new K/V (B, Hkv, D) at ``[layer, b, :, slot[b]]``
+    of stacked (L, B, Hkv, S, D) caches; returns the updated caches.
+    The Pallas writer updates the buffers in place."""
+    if _resolve(backend) == "pallas":
+        return _per_shard(
+            lambda kr, vr, s, i, k, v: _kw.kv_row_write(
+                k, v, kr, vr, s, i, interpret=not _on_tpu()
+            ),
+            k_row, v_row, slot, layer, k_cache, v_cache,
+            dims=(("b", "h", None), ("b", "h", None), ("b",), (),
+                  _STACK, _STACK),
+            out_dims=(_STACK, _STACK), heads=k_cache.shape[2],
+            kv_cache=True,
+        )
+    # per (row, head) indices: each update is one contiguous D-vector, so
+    # the cache keeps its row-major layout (a window over heads and D
+    # makes XLA lay the cache out position-major and relayout it)
+    bidx = jnp.arange(k_cache.shape[1])[:, None]
+    hidx = jnp.arange(k_cache.shape[2])[None, :]
+    sidx = slot[:, None]
+    return (
+        k_cache.at[layer, bidx, hidx, sidx].set(k_row.astype(k_cache.dtype)),
+        v_cache.at[layer, bidx, hidx, sidx].set(v_row.astype(v_cache.dtype)),
+    )
 
 
 def prefill_attention(

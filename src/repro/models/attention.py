@@ -4,7 +4,9 @@ Three execution paths per block, matching the assigned shapes:
 
 * ``train``    — full-sequence causal/windowed attention, differentiable;
 * ``prefill``  — same math, also materializes the KV cache;
-* ``decode``   — one token against the cache (flash-decode datapath).
+* ``decode``   — one token against the cache (flash-decode datapath);
+  GQA decode takes the layer-stacked cache and a layer index, writes its
+  new row there in place and reads the same buffer.
 
 MLA (DeepSeek-V2) caches the 512-dim latent + shared rope key and uses the
 **absorbed** decode formulation (q absorbed through W_uk, output through
@@ -26,6 +28,7 @@ import jax.numpy as jnp
 
 from repro.configs import AttentionSpec
 from repro.kernels import ops
+from repro.kernels.kv_write import TILE_ROWS
 from repro.models.layers import rope
 from repro.models.sharding import Param, shard
 
@@ -129,6 +132,11 @@ def cache_defs(
     size = min(max_len, spec.window) if code == "L" and spec.window else max_len
     if code == "C" and spec.chunk:
         size = min(max_len, 2 * spec.chunk)  # ring over current+prev chunk
+    if size == max_len:
+        # a cache no ring wraps ends on a whole row tile: the TPU lays out
+        # a (.., size, D) array whose size no tile divides position-major,
+        # and the decode kernels would copy it back to row-major each step
+        size = -(-max_len // TILE_ROWS) * TILE_ROWS
     return {
         "k": Param(
             (batch, spec.n_kv_heads, size, spec.d_head),
@@ -274,9 +282,17 @@ def gqa_prefill_at(
     return shard(out, "batch", "seq", "embed"), cache
 
 
-def gqa_decode(params, x, cache, lengths, spec: AttentionSpec, code: str):
-    """One-token decode; x (B,1,D); lengths (B,) tokens already cached."""
-    B = x.shape[0]
+def gqa_decode_at(
+    params, x, cache, layer, lengths, spec: AttentionSpec, code: str
+):
+    """One-token decode against layer ``layer`` of a stacked cache.
+
+    ``x`` (B,1,D); ``lengths`` (B,) tokens already cached; ``cache``
+    holds (L, B, Hkv, size, D) K and V.  The new row is written in place
+    (:func:`repro.kernels.ops.kv_row_write`, at ring slot
+    ``lengths % size``) and attention reads the same stacked buffer at
+    ``layer``: no layer slice of the cache is materialised.
+    """
     positions = lengths[:, None, None]           # (B,1,1) for (B,H,1,dh)
     q = jnp.einsum("bsd,dhk->bhsk", x, params["w_q"])
     k = jnp.einsum("bsd,dhk->bhsk", x, params["w_k"])
@@ -289,11 +305,11 @@ def gqa_decode(params, x, cache, lengths, spec: AttentionSpec, code: str):
     k = rope(k, positions, th)[:, :, 0]          # (B,Hkv,D)
     v = v[:, :, 0]
 
-    size = cache["k"].shape[2]
+    size = cache["k"].shape[3]
     slot = (lengths % size).astype(jnp.int32)    # ring index
-    bidx = jnp.arange(B)
-    k_cache = cache["k"].at[bidx, :, slot].set(k.astype(cache["k"].dtype))
-    v_cache = cache["v"].at[bidx, :, slot].set(v.astype(cache["v"].dtype))
+    k_cache, v_cache = ops.kv_row_write(
+        cache["k"], cache["v"], k, v, slot, layer
+    )
 
     if code == "L" and spec.window:
         valid = jnp.minimum(lengths + 1, size)
@@ -304,9 +320,19 @@ def gqa_decode(params, x, cache, lengths, spec: AttentionSpec, code: str):
         valid = jnp.minimum(valid, size)
     else:
         valid = jnp.minimum(lengths + 1, size)
-    o = ops.decode_attention(q, k_cache, v_cache, valid)
+    o = ops.decode_attention(q, k_cache, v_cache, valid, layer)
     out = jnp.einsum("bhk,hkd->bd", o, params["w_o"])[:, None]
     return shard(out, "batch", "seq", "embed"), {"k": k_cache, "v": v_cache}
+
+
+def gqa_decode(params, x, cache, lengths, spec: AttentionSpec, code: str):
+    """:func:`gqa_decode_at` on a one-layer (B, Hkv, size, D) cache, as
+    the encoder-decoder's layer scan slices it."""
+    out, c = gqa_decode_at(
+        params, x, jax.tree.map(lambda a: a[None], cache), jnp.int32(0),
+        lengths, spec, code,
+    )
+    return out, jax.tree.map(lambda a: a[0], c)
 
 
 # ---------------------------------------------------------------------------
@@ -491,9 +517,3 @@ def attn_prefill_at(params, x, cache, offsets, new_lens, spec, code):
     if spec.kind == "mla":
         return mla_prefill_at(params, x, cache, offsets, new_lens, spec, code)
     return gqa_prefill_at(params, x, cache, offsets, new_lens, spec, code)
-
-
-def attn_decode(params, x, cache, lengths, spec, code):
-    if spec.kind == "mla":
-        return mla_decode(params, x, cache, lengths, spec, code)
-    return gqa_decode(params, x, cache, lengths, spec, code)

@@ -9,6 +9,13 @@ Hybrid patterns: ``M`` layers are Mamba-2 blocks; ``S`` is the Zamba-style
 *shared* attention block whose parameters live once at model level and are
 closed over by every stage body (scan-invariant), with per-application KV
 caches.
+
+Decode carries each stage's stacked cache through its layer scan
+(:func:`_run_stages_decode`): a GQA layer writes its new K/V row into the
+stack in place and attention reads the same buffer, so a decode step
+moves no layer-sized slice of the KV cache.  Prefill still scans the
+cache as ``xs``/``ys`` (:func:`_run_stages_step`), slicing each layer out
+and restacking it.
 """
 
 from __future__ import annotations
@@ -163,7 +170,7 @@ def _apply_layer_train(cfg, code, lp, x, emb0, shared):
 def _apply_layer_step(
     cfg, code, lp, cache, x, emb0, lengths, shared, mode, new_lens=None
 ):
-    """prefill/prefill_at/decode step for one layer; returns (x, new_cache).
+    """prefill/prefill_at step for one layer; returns (x, new_cache).
 
     ``prefill_at`` is the serving engine's chunked batched prefill:
     ``lengths`` carries each row's cache fill *offset* and ``new_lens`` how
@@ -173,44 +180,84 @@ def _apply_layer_step(
         h = apply_norm(lp["norm"], x, cfg.norm)
         if mode == "prefill":
             out, c = ssm_mod.ssm_prefill(lp["ssm"], h, cache, cfg.d_model, cfg.ssm)
-        elif mode == "prefill_at":
+        else:
             out, c = ssm_mod.ssm_prefill_at(
                 lp["ssm"], h, cache, lengths, new_lens, cfg.d_model, cfg.ssm
             )
-        else:
-            out, c = ssm_mod.ssm_decode(lp["ssm"], h, cache, cfg.d_model, cfg.ssm)
         return x + out, c
     if code == "S":
         xin = jnp.concatenate([x, emb0], axis=-1)
         xin = apply_norm(shared["norm"], xin, cfg.norm)
         if mode == "prefill":
             out, c = attn.gqa_prefill(shared, xin, cache, cfg.attention, "F")
-        elif mode == "prefill_at":
+        else:
             out, c = attn.gqa_prefill_at(
                 shared, xin, cache, lengths, new_lens, cfg.attention, "F"
-            )
-        else:
-            out, c = attn.gqa_decode(
-                shared, xin, cache, lengths, cfg.attention, "F"
             )
         return x + out, c
     h = apply_norm(lp["attn_norm"], x, cfg.norm)
     if mode == "prefill":
         out, c = attn.attn_prefill(lp["attn"], h, cache, cfg.attention, code)
-    elif mode == "prefill_at":
+    else:
         out, c = attn.attn_prefill_at(
             lp["attn"], h, cache, lengths, new_lens, cfg.attention, code
         )
-    else:
-        out, c = attn.attn_decode(
-            lp["attn"], h, cache, lengths, cfg.attention, code
-        )
-    x = x + out
+    return _apply_ffn(cfg, lp, x + out), c
+
+
+def _apply_ffn(cfg, lp, x):
+    """The MLP (or MoE) half of an attention layer, residual included."""
     h = apply_norm(lp["mlp_norm"], x, cfg.norm)
     if "moe" in lp:
         out, _ = moe_mod.apply_moe(lp["moe"], h, cfg.moe, cfg.act)
-        return x + out, c
-    return x + apply_mlp(lp["mlp"], h, cfg.act), c
+        return x + out
+    return x + apply_mlp(lp["mlp"], h, cfg.act)
+
+
+def _at_layer(fn, cache, i):
+    """``fn`` on layer ``i`` of a stacked cache; returns ``fn``'s output
+    and the cache with the new layer written back at ``i``."""
+    out, c = fn(jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), cache
+    ))
+    return out, jax.tree.map(
+        lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, i, 0),
+        cache, c,
+    )
+
+
+def _apply_layer_decode(cfg, code, lp, cache, i, x, emb0, lengths, shared):
+    """Decode one layer against layer ``i`` of its stacked cache; returns
+    (x, cache).  GQA caches (full, ring ``L``, chunk ``C``, the shared
+    ``S`` block's) take the new row in place; SSM state and MLA latents
+    are sliced out and written back."""
+    if code == "M":
+        h = apply_norm(lp["norm"], x, cfg.norm)
+        out, cache = _at_layer(
+            lambda c: ssm_mod.ssm_decode(lp["ssm"], h, c, cfg.d_model, cfg.ssm),
+            cache, i,
+        )
+        return x + out, cache
+    if code == "S":
+        xin = jnp.concatenate([x, emb0], axis=-1)
+        xin = apply_norm(shared["norm"], xin, cfg.norm)
+        out, cache = attn.gqa_decode_at(
+            shared, xin, cache, i, lengths, cfg.attention, "F"
+        )
+        return x + out, cache
+    h = apply_norm(lp["attn_norm"], x, cfg.norm)
+    if cfg.attention.kind == "mla":
+        out, cache = _at_layer(
+            lambda c: attn.mla_decode(
+                lp["attn"], h, c, lengths, cfg.attention, code
+            ),
+            cache, i,
+        )
+    else:
+        out, cache = attn.gqa_decode_at(
+            lp["attn"], h, cache, i, lengths, cfg.attention, code
+        )
+    return _apply_ffn(cfg, lp, x + out), cache
 
 
 # ---------------------------------------------------------------------------
@@ -261,6 +308,10 @@ def _run_stages_train(cfg, params, x, remat: str):
 
 
 def _run_stages_step(cfg, params, caches, x, lengths, mode, new_lens=None):
+    """Prefill scan: each layer's cache is sliced in as ``xs`` and
+    restacked as ``ys``.  Its cache write is a chunk at unaligned offsets;
+    prefill is expected to join :func:`_run_stages_decode` once a chunk
+    writer exists."""
     shared = params.get("shared_attn")
     emb0 = x if "S" in cfg.layer_pattern else jnp.zeros((1,), x.dtype)
     new_caches = []
@@ -285,6 +336,42 @@ def _run_stages_step(cfg, params, caches, x, lengths, mode, new_lens=None):
             body, (x, emb0), (stage_params, stage_cache)
         )
         new_caches.append(nc)
+    return x, {"stages": new_caches}
+
+
+def _run_stages_decode(cfg, params, caches, x, lengths):
+    """Decode scan: each stage's stacked cache and the layer index ride
+    in the carry; parameters stay ``xs``.
+
+    A GQA layer writes its new K/V row into the stack in place and its
+    attention reads the same buffer at the layer index, so no layer slice
+    of the cache is materialised (the ``xs``/``ys`` scan of
+    :func:`_run_stages_step` copies every layer out and back, and the
+    whole stack once more).  Prefill is expected to join this scan once a
+    writer for its chunks exists.
+    """
+    shared = params.get("shared_attn")
+    emb0 = x if "S" in cfg.layer_pattern else jnp.zeros((1,), x.dtype)
+    new_caches = []
+    for (codes, _, _), stage_params, stage_cache in zip(
+        cfg.stages(), params["stages"], caches["stages"]
+    ):
+        def body(carry, lp, _codes=codes):
+            x, cache, i = carry
+            cache = dict(cache)
+            for j, code in enumerate(_codes):
+                key = f"{j}{code}"
+                x, cache[key] = _apply_layer_decode(
+                    cfg, code, lp[key], cache[key], i, x, emb0, lengths,
+                    shared,
+                )
+            x = shard(x, "batch", "seq", "embed")
+            return (x, cache, i + 1), None
+
+        (x, cache, _), _ = jax.lax.scan(
+            body, (x, stage_cache, jnp.int32(0)), stage_params
+        )
+        new_caches.append(cache)
     return x, {"stages": new_caches}
 
 
@@ -373,7 +460,7 @@ def lm_decode_step(params, tokens, caches, lengths, cfg: ArchConfig):
     Returns (logits (B, vocab), new caches).  Caller advances lengths.
     """
     x = apply_embed(params["embed"], tokens)
-    x, caches = _run_stages_step(cfg, params, caches, x, lengths, "decode")
+    x, caches = _run_stages_decode(cfg, params, caches, x, lengths)
     x = apply_norm(params["final_norm"], x, cfg.norm)
     logits = apply_head(params["head"], params["embed"], x)
     return logits[:, 0], caches

@@ -15,8 +15,10 @@ The serve package is layered (see ``docs/serving.md``):
   batches of tokens and cache rows.
 
 The hot path keeps the zero-copy discipline of the Fig. 17 rework —
-per decoded token every parameter byte and cache byte crosses the
-compute datapath exactly once:
+per decode step every parameter byte is read once, each cache layer is
+read once by the attention kernel and only the new K/V rows are written
+(the model's decode scan writes them in place; prefill still slices
+each layer's cache out of its scan and back):
 
 * **Donated caches** — decode/prefill jits donate the cache pytree
   (gated per policy by ``donation_compatible``; ``Strategy.STREAM``

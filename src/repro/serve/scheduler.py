@@ -303,12 +303,16 @@ class Server:
         ``cancelled``/``expired``/``tier_losses``/``spill_corruptions``/
         ``requeued_fresh``/``watchdog_stalls``/``watchdog_retries``/
         ``watchdog_evacuations``) and the live ``queued``/``spilled``
-        depths."""
+        depths; ``decode_cache_copies`` is the build-time audit's count of
+        cache-sized copies in the compiled decode step (0 when every K/V
+        row is written in place)."""
         return {
             **self.engine.counters,
             **self._counters,
             "queued": self.queue_depth,
             "spilled": len(self._spilled),
+            "decode_cache_copies":
+                self.engine.audit_reports["decode"].cache_sized_copies,
         }
 
     def throughput(self) -> dict:

@@ -20,7 +20,10 @@ from repro.analysis.hlo_audit import (
     RoleExpectation,
     audit_compiled,
     audit_hlo_text,
+    cache_sized_instructions,
+    count_cache_sized_copies,
 )
+from repro.core.hlo_analysis import parse_hlo
 from repro.core.placement import Role, registered_policies
 
 # -- synthetic post-SPMD modules -------------------------------------------
@@ -52,6 +55,39 @@ ENTRY %main (p0: f32[1024]) -> f32[1024] {
   %p0 = f32[1024]{0} parameter(0), metadata={op_name="caches[0]"}
   %cs = (f32[1024]{0:S(5)}, f32[1024]{0}, u32[]) copy-start(%p0)
   ROOT %cd = f32[1024]{0:S(5)} copy-done(%cs)
+}
+"""
+
+# a copy and a fused layer slice of the stacked cache count; the
+# parameter, the in-place row write, the writer's custom call and the
+# tuple do not
+CACHE_COPIES = """\
+HloModule cache_copies, input_output_alias={ {0}: (0, {}, may-alias) }
+
+%fused_slice (param_0: bf16[4,2,8,16], param_1: s32[]) -> bf16[1,2,8,16] {
+  %param_0 = bf16[4,2,8,16]{3,2,1,0} parameter(0)
+  %param_1 = s32[] parameter(1)
+  %zero = s32[] constant(0)
+  ROOT %ds = bf16[1,2,8,16]{3,2,1,0} dynamic-slice(%param_0, %param_1, %zero, %zero, %zero), dynamic_slice_sizes={1,2,8,16}
+}
+
+%fused_row (param_0: bf16[4,2,8,16], param_1: bf16[1,1,1,16], param_2: s32[]) -> bf16[4,2,8,16] {
+  %param_0 = bf16[4,2,8,16]{3,2,1,0} parameter(0)
+  %param_1 = bf16[1,1,1,16]{3,2,1,0} parameter(1)
+  %param_2 = s32[] parameter(2)
+  %zero = s32[] constant(0)
+  ROOT %dus = bf16[4,2,8,16]{3,2,1,0} dynamic-update-slice(%param_0, %param_1, %param_2, %zero, %zero, %zero)
+}
+
+ENTRY %main (p0: bf16[4,2,8,16], p1: s32[], p2: bf16[1,1,1,16]) -> (bf16[4,2,8,16], bf16[1,2,8,16], bf16[4,2,8,16], bf16[4,2,8,16]) {
+  %p0 = bf16[4,2,8,16]{3,2,1,0} parameter(0), metadata={op_name="caches[0]"}
+  %p1 = s32[] parameter(1), metadata={op_name="i"}
+  %p2 = bf16[1,1,1,16]{3,2,1,0} parameter(2), metadata={op_name="row"}
+  %relayout = bf16[4,2,8,16]{2,3,1,0} copy(%p0)
+  %layer = bf16[1,2,8,16]{3,2,1,0} fusion(%p0, %p1), kind=kLoop, calls=%fused_slice
+  %row_write = bf16[4,2,8,16]{3,2,1,0} fusion(%p0, %p2, %p1), kind=kLoop, calls=%fused_row
+  %writer = bf16[4,2,8,16]{3,2,1,0} custom-call(%row_write, %p1), custom_call_target="tpu_custom_call"
+  ROOT %t = (bf16[4,2,8,16]{3,2,1,0}, bf16[1,2,8,16]{3,2,1,0}, bf16[4,2,8,16]{2,3,1,0}, bf16[4,2,8,16]{3,2,1,0}) tuple(%writer, %layer, %relayout, %row_write)
 }
 """
 
@@ -137,6 +173,24 @@ class TestAuditHloText:
         assert rep.ok and rep.donation_expected == 0
         assert rep.donation_coverage == 1.0
 
+    def test_cache_sized_copies_counted(self):
+        rep = audit_hlo_text(CACHE_COPIES, _kv(donate=True))
+        assert rep.cache_sized_copies == 2
+        # every cache-sized buffer is seen; only the copy and the slice count
+        shapes = [("bf16", (4, 2, 8, 16))]
+        comps = parse_hlo(CACHE_COPIES)
+        assert sorted(
+            ins.name for ins, _ in cache_sized_instructions(comps, shapes)
+        ) == ["layer", "relayout", "row_write", "writer"]
+        assert count_cache_sized_copies(comps, shapes) == 2
+        assert rep.to_json()["cache_sized_copies"] == 2
+        # a report, never a violation
+        assert rep.ok and rep.violations == []
+        # only cache shapes count: without a cache role nothing does
+        assert audit_hlo_text(
+            CACHE_COPIES, ExpectedMovement(label="test")
+        ).cache_sized_copies == 0
+
     def test_to_json_round_trips(self):
         import json
 
@@ -216,6 +270,23 @@ def smoke():
     bundle = get_smoke_bundle("olmo-1b")
     params = bundle.init_params(jax.random.PRNGKey(0), "float32")
     return bundle, params
+
+
+def test_smoke_server_decode_holds_no_cache_copies(smoke):
+    """The decode step writes each K/V row in place: its compiled module
+    holds no copy or slice of a cache layer, where the prefill step's
+    xs/ys layer scan still slices each layer out and back."""
+    from repro.serve import ServeConfig, Server
+
+    bundle, params = smoke
+    srv = Server(
+        bundle, ServeConfig(batch_slots=2, max_len=32, prefill_chunk=4),
+        params,
+    )
+    assert srv.stats()["decode_cache_copies"] == 0
+    assert srv.engine.audit_reports["prefill"].cache_sized_copies > 0
+    # the insert step's row write updates the cache in place: no copy
+    assert srv.engine.audit_reports["insert"].cache_sized_copies == 0
 
 
 @pytest.mark.parametrize("policy_name", sorted(registered_policies()))

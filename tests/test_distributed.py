@@ -71,10 +71,22 @@ class TestPallasPerShard:
         a = -jnp.exp(jax.random.normal(ks[6], (4,)) * 0.5)
         bm = jax.random.normal(ks[7], (4, 64, 16))
         cm = jax.random.normal(ks[0], (4, 64, 16))
+        # the decode step's layer-stacked caches: layer 1 holds kc / vc
+        kv5 = NamedSharding(mesh, P(None, *{kv_spec}))
+        ks5 = jax.device_put(jnp.stack([vc, kc]), kv5)
+        vs5 = jax.device_put(jnp.stack([kc, vc]), kv5)
+        layer = jnp.int32(1)
+        krow = jax.random.normal(ks[5], (4, 4, 32))
+        vrow = jax.random.normal(ks[6], (4, 4, 32))
+        slots = jnp.asarray([0, 99, 255, 36], jnp.int32)
 
         def dec(backend):
             return jax.jit(lambda q, k, v, l: ops.decode_attention(
-                q, k, v, l, backend=backend))
+                q, k, v, l, layer, backend=backend))
+
+        def wr(backend):
+            return jax.jit(lambda k, v, kr, vr, s: ops.kv_row_write(
+                k, v, kr, vr, s, layer, backend=backend))
 
         def pre(backend):
             return jax.jit(lambda q, k, v, a, b: ops.prefill_attention(
@@ -88,15 +100,21 @@ class TestPallasPerShard:
         for backend in ("pallas", "ref"):
             with use_sharding(mesh, kv_donor_axes={donor!r}):
                 out[backend] = (
-                    dec(backend)(q, kc, vc, lens),
+                    dec(backend)(q, ks5, vs5, lens),
                     pre(backend)(qc, kc, vc, qpos, kpos),
                     ssd(backend)(x, dt, a, bm, cm),
+                    wr(backend)(ks5, vs5, krow, vrow, slots),
                 )
                 if backend == "pallas":
                     hlo = [
                         dec(backend).lower(
-                            jax.device_put(q, rep), kc, vc,
+                            jax.device_put(q, rep), ks5, vs5,
                             jax.device_put(lens, rep),
+                        ).compile().as_text(),
+                        wr(backend).lower(
+                            ks5, vs5, jax.device_put(krow, rep),
+                            jax.device_put(vrow, rep),
+                            jax.device_put(slots, rep),
                         ).compile().as_text(),
                         pre(backend).lower(
                             jax.device_put(qc, rep), kc, vc,

@@ -56,21 +56,66 @@ def test_flash_attention_matches_ref(B, Hq, Hkv, S, D, kind, kw, dtype):
 @pytest.mark.parametrize(
     "B,Hq,Hkv,Smax,D",
     # 600: the last 512-row tile overhangs the cache
-    [(2, 4, 2, 256, 32), (3, 8, 8, 512, 64), (2, 4, 4, 600, 32)],
+    [(2, 4, 2, 256, 32), (3, 8, 8, 512, 64), (2, 4, 4, 600, 32),
+     (1, 8, 1, 128, 64)],
 )
 def test_flash_decode_matches_ref(B, Hq, Hkv, Smax, D, dtype):
+    # the decode step's layer-stacked cache: the kernel reads one layer of
+    # it at a scalar-prefetched index, the oracle that layer alone
+    L, layer = 3, 1
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(ks[0], (B, Hq, D), dtype)
-    kc = jax.random.normal(ks[1], (B, Hkv, Smax, D), dtype)
-    vc = jax.random.normal(ks[2], (B, Hkv, Smax, D), dtype)
+    kc = jax.random.normal(ks[1], (L, B, Hkv, Smax, D), dtype)
+    vc = jax.random.normal(ks[2], (L, B, Hkv, Smax, D), dtype)
     lengths = jnp.asarray(
         np.random.default_rng(0).integers(1, Smax + 1, size=B), jnp.int32
     )
-    out = ops.decode_attention(q, kc, vc, lengths, backend="pallas")
-    want = ops.decode_attention(q, kc, vc, lengths, backend="ref")
+    out = ops.decode_attention(
+        q, kc, vc, lengths, jnp.int32(layer), backend="pallas"
+    )
+    want = ref.decode_attention(q, kc[layer], vc[layer], lengths)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(want, np.float32), **_tol(dtype)
     )
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize(
+    "lengths,size,layer",
+    [
+        (list(range(8)), 64, 0),            # slots 0-7 of one tile
+        ([63, 63], 64, 2),                  # the last position
+        ([5, 70, 133, 64], 64, 1),          # ring wrap: an L cache of 64
+        ([0, 17, 40, 9, 63, 31], 64, 3),    # rows of different lengths
+        ([3, 26, 11], 12, 1),               # no tile divides the ring
+        ([1499, 1496, 7, 1000], 1500, 2),   # rows in a partial last tile
+    ],
+    ids=["tile-slots", "last-position", "ring-wrap", "mixed-lengths",
+         "unaligned-ring", "partial-tile"],
+)
+def test_kv_row_write_matches_set(lengths, size, layer, dtype):
+    L, Hkv, D = 4, 2, 128
+    B = len(lengths)
+    ks = jax.random.split(jax.random.PRNGKey(8), 4)
+    kc = jax.random.normal(ks[0], (L, B, Hkv, size, D), dtype)
+    vc = jax.random.normal(ks[1], (L, B, Hkv, size, D), dtype)
+    krow = jax.random.normal(ks[2], (B, Hkv, D), dtype)
+    vrow = jax.random.normal(ks[3], (B, Hkv, D), dtype)
+    slot = jnp.asarray(lengths, jnp.int32) % size
+    got = ops.kv_row_write(
+        kc, vc, krow, vrow, slot, jnp.int32(layer), backend="pallas"
+    )
+    bidx = jnp.arange(B)
+    want = (kc.at[layer, bidx, :, slot].set(krow),
+            vc.at[layer, bidx, :, slot].set(vrow))
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    # the oracle path of the writer is that same scatter
+    ref_path = ops.kv_row_write(
+        kc, vc, krow, vrow, slot, jnp.int32(layer), backend="ref"
+    )
+    for r, w in zip(ref_path, want):
+        np.testing.assert_array_equal(np.asarray(r), np.asarray(w))
 
 
 def test_chunked_attention_matches_full():

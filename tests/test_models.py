@@ -129,6 +129,125 @@ class TestCacheConsistency:
         )
 
 
+def _xs_ys_decode(cfg, params, caches, tokens, lengths):
+    """The decode scan the carried one replaced: each layer's cache is
+    sliced in as the scan's ``xs`` and restacked as its ``ys``."""
+    from repro.models import transformer as tf
+    from repro.models.layers import apply_embed, apply_head, apply_norm
+
+    x = apply_embed(params["embed"], tokens)
+    shared = params.get("shared_attn")
+    emb0 = x if "S" in cfg.layer_pattern else jnp.zeros((1,), x.dtype)
+    stages = []
+    for (codes, _, _), sp, sc in zip(
+        cfg.stages(), params["stages"], caches["stages"]
+    ):
+        def body(x, slices, _codes=codes):
+            lp, cache = slices
+            new = {}
+            for j, code in enumerate(_codes):
+                key = f"{j}{code}"
+                one = jax.tree.map(lambda a: a[None], cache[key])
+                x, c = tf._apply_layer_decode(
+                    cfg, code, lp[key], one, jnp.int32(0), x, emb0, lengths,
+                    shared,
+                )
+                new[key] = jax.tree.map(lambda a: a[0], c)
+            return x, new
+
+        x, nc = jax.lax.scan(body, x, (sp, sc))
+        stages.append(nc)
+    x = apply_norm(params["final_norm"], x, cfg.norm)
+    logits = apply_head(params["head"], params["embed"], x)
+    return logits[:, 0], {"stages": stages}
+
+
+class TestDecodeScan:
+    """The decode step carries each stage's stacked cache through its
+    layer scan and writes K/V rows in place; it must give what slicing
+    each layer's cache in and out of the scan gives, bit for bit."""
+
+    # F full, L ring (window 32 < max_len: wraps) + G, C chunk ring,
+    # M SSM state + S shared attention, MLA latents
+    @pytest.mark.parametrize("arch", [
+        "olmo-1b", "gemma3-27b", "llama4-maverick-400b-a17b",
+        "zamba2-1.2b", "deepseek-v2-236b",
+    ])
+    def test_carried_cache_matches_xs_ys_scan(self, arch):
+        from repro.models.transformer import lm_decode_step
+
+        b = get_smoke_bundle(arch)
+        cfg = b.cfg
+        params = b.init_params(jax.random.PRNGKey(1), "float32")
+        B, max_len = 3, 40
+        cache = b.init_cache(B, max_len)
+        keys = iter(jax.random.split(jax.random.PRNGKey(2), 64))
+        cache = jax.tree.map(
+            lambda a: jax.random.normal(next(keys), a.shape, a.dtype), cache
+        )
+        new = jax.jit(lambda p, t, c, l: lm_decode_step(p, t, c, l, cfg))
+        old = jax.jit(lambda p, t, c, l: _xs_ys_decode(cfg, p, c, t, l))
+        lengths = jnp.asarray([30, 5, 35], jnp.int32)
+        got_cache = want_cache = cache
+        for step in range(4):
+            toks = jax.random.randint(next(keys), (B, 1), 0, cfg.vocab)
+            got, got_cache = new(params, toks, got_cache, lengths + step)
+            want, want_cache = old(params, toks, want_cache, lengths + step)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+            for g, w in zip(
+                jax.tree.leaves(got_cache), jax.tree.leaves(want_cache)
+            ):
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+    @pytest.mark.parametrize("arch", [
+        "olmo-1b", "gemma3-27b", "llama4-maverick-400b-a17b",
+        "zamba2-1.2b", "deepseek-v2-236b",
+    ])
+    def test_kernel_path_matches_oracle_path(self, arch, monkeypatch):
+        """The decode step on the TPU's path (the in-place row writer and
+        the layer-indexed flash_decode, interpreted here) against the
+        same step on the jnp oracles, for every layer type."""
+        from repro.kernels import ops
+        from repro.models.transformer import lm_decode_step
+
+        b = get_smoke_bundle(arch)
+        cfg = b.cfg
+        params = b.init_params(jax.random.PRNGKey(1), "float32")
+        B, max_len = 3, 44   # padded to 48 positions for the writer
+        cache = b.init_cache(B, max_len, dtype="float32")
+        keys = iter(jax.random.split(jax.random.PRNGKey(3), 64))
+        cache = jax.tree.map(
+            lambda a: jax.random.normal(next(keys), a.shape, a.dtype), cache
+        )
+        lengths = jnp.asarray([30, 5, 41], jnp.int32)
+        toks = [jax.random.randint(next(keys), (B, 1), 0, cfg.vocab)
+                for _ in range(2)]
+
+        def run():
+            step = jax.jit(lambda p, t, c, l: lm_decode_step(p, t, c, l, cfg))
+            c, out = cache, []
+            for i, t in enumerate(toks):
+                logits, c = step(params, t, c, lengths + i)
+                out.append(logits)
+            return out, c
+
+        want, want_cache = run()
+        monkeypatch.setattr(
+            ops, "_resolve", lambda backend: backend or "pallas"
+        )
+        got, got_cache = run()
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(
+                np.asarray(g), np.asarray(w), atol=1e-4, rtol=1e-4
+            )
+        for g, w in zip(
+            jax.tree.leaves(got_cache), jax.tree.leaves(want_cache)
+        ):
+            np.testing.assert_allclose(
+                np.asarray(g), np.asarray(w), atol=1e-4, rtol=1e-4
+            )
+
+
 class TestMoE:
     def _setup(self, top_k=2, E=8, cf=2.0):
         from repro.configs import MoESpec
