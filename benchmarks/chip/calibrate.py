@@ -104,7 +104,7 @@ def main() -> None:
         del server
         gc.collect()
         watched = [r for r in win.sent if r.rid in tap.rids]
-        args_ = (params, cell.model, watched, tap.got,
+        args_ = (params, cell.family.forward, cell.model, watched, tap.got,
                  cell.serve["max_len"], cell.serve["prefill_chunk"])
         t1 = time.perf_counter()
         got = check.compare(*args_)

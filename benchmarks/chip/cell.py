@@ -1,7 +1,10 @@
 """A cell of ``BENCHMARK.json`` and the files it names.
 
 ``configs/<config>.json`` holds the model as it is run (its sizes under
-the source's own keys), the server's settings and the correctness limit;
+the source's own keys), its ``family``, the server's settings and the
+correctness limit; ``families/<family>.py`` what depends on the kind of
+model (the program's config, the needed work, the reference and the
+weights' scale: ``families/dense.py`` says what it gives);
 ``traffic/<traffic>.json`` the mix; ``metrics/<metric>.py`` the reader of
 each per-layer metric, or ``metrics/<base>.py`` for a metric named
 ``<base>.<cells>`` (one reader serves the split of a quantity by the
@@ -11,9 +14,11 @@ end-to-end metric it moves).  Everything is found by name.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
+from types import ModuleType
 
 HERE = Path(__file__).resolve().parent
 
@@ -23,6 +28,7 @@ class Cell:
     name: str
     chips: int
     config: dict
+    family: ModuleType      # families/<config's family>.py
     traffic: dict
     end_to_end: list        # BENCHMARK.json entries this cell reports
     per_layer: list
@@ -59,10 +65,29 @@ def load_cell(bench: dict, workload: str, home: Path = HERE) -> Cell:
         name=workload,
         chips=w["chips"],
         config=config,
+        family=load_family(config["family"], home),
         traffic=load_json(home / "traffic" / f"{w['traffic']}.json"),
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, workload)],
         per_layer=[m for m in bench["per_layer"] if _applies(m, workload)],
     )
+
+
+@functools.cache
+def _module(path: Path, name: str) -> ModuleType:
+    """The module in the file ``path``, loaded once per process."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_family(family: str, home: Path = HERE) -> ModuleType:
+    """``families/<family>.py``."""
+    path = home / "families" / f"{family}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no family module {path} for family "
+                                f"{family!r}")
+    return _module(path, f"chip_family_{family}")
 
 
 def metric_reader(name: str, home: Path = HERE):
@@ -71,41 +96,4 @@ def metric_reader(name: str, home: Path = HERE):
     path = home / "metrics" / f"{name}.py"
     if not path.is_file():
         path = home / "metrics" / f"{name.split('.')[0]}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"chip_metric_{name.replace('.', '_')}", path
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
-
-
-def arch_config(config: dict):
-    """The program's ``ArchConfig`` with the sizes the file states; any
-    size the program would run differently is an error."""
-    from repro.configs import get_config
-
-    m = config["model"]
-    base = get_config(config["arch"])
-    cfg = dataclasses.replace(
-        base,
-        n_layers=m["num_hidden_layers"],
-        d_model=m["hidden_size"],
-        d_ff=m["intermediate_size"],
-        vocab=m["vocab_size"],
-        norm=m["norm"],
-        tie_embeddings=m["tie_word_embeddings"],
-        dtype=m["dtype"],
-        act={"silu": "silu"}[m["hidden_act"]],
-        attention=dataclasses.replace(
-            base.attention,
-            n_heads=m["num_attention_heads"],
-            n_kv_heads=m["num_key_value_heads"],
-            d_head=m["head_dim"],
-            rope_theta=float(m["rope_theta"]),
-        ),
-    )
-    if cfg.layer_pattern != "F" or cfg.family != "dense":
-        raise NotImplementedError(
-            f"{cfg.name}: the harness serves dense full-attention decoders"
-        )
-    return cfg
+    return _module(path, f"chip_metric_{path.stem}").read

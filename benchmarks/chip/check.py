@@ -118,11 +118,12 @@ def _rel(err_sumsq, err_max, scale, vocab):
     return rms / scale, float(err_max.max()) / scale
 
 
-def compare(params, model: dict, requests, got: dict, length: int,
-            chunk: int, low=None, scope: str = "all") -> dict:
+def compare(params, forward, model: dict, requests, got: dict,
+            length: int, chunk: int, low=None, scope: str = "all") -> dict:
     """The numbers of the module's docstring over ``requests`` (each
     with ``prompt``, ``out_tokens`` and ``rid``), their logits as
-    ``Tap.got`` holds them; with ``low``, the control's as ``low_<name>``.
+    ``Tap.got`` holds them, against the family's reference ``forward``;
+    with ``low``, the control's as ``low_<name>``.
     Also ``tokens`` (served tokens compared), ``dispatches`` and
     ``live_min`` (the fewest slots live at a compared dispatch)."""
     worst = dict.fromkeys(NUMBERS, None)
@@ -147,8 +148,8 @@ def compare(params, model: dict, requests, got: dict, length: int,
         pos = np.array([p for _, p, _, _ in taps], np.int32)
         served = np.stack([lg for _, _, _, lg in taps])
         is_pre = np.array([s == "prefill" for s, _, _, _ in taps])
-        st = reference.position_stats(params, model, seq, length, pos,
-                                      served, low=low, scope=scope)
+        st = reference.position_stats(params, forward, model, seq, length,
+                                      pos, served, low=low, scope=scope)
         n_tok += len(out)
         n_disp += len(taps)
         lm = min(lv for _, _, lv, _ in taps)

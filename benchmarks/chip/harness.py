@@ -9,7 +9,11 @@ of every decode step, and brackets each layer's call in a host span
 that the profiler's trace carries.  Its ``logits_tap`` keeps the logits
 the window serves to a few requests drawn from the seed, which the
 check holds to the float32 reference once the window has closed
-(``check.py``).
+(``check.py``).  The server's counters (``Server.stats()``) are read as
+the window opens and once the step that straddles its close has ended;
+a per-layer reader gets their change (``Window.counters``) and the
+program's ``serve.*`` spans (``Trace.spans``).  What depends on the kind
+of model comes from the cell's family (``families/<family>.py``).
 """
 
 from __future__ import annotations
@@ -24,8 +28,8 @@ import time
 import jax
 import numpy as np
 
-from . import check, counts, tracefile, traffic, weights
-from .cell import Cell, arch_config, metric_reader
+from . import check, tracefile, traffic, weights
+from .cell import Cell, metric_reader
 from .peaks import peaks_for
 
 HOST_SPANS = ("Server.step", "Executor.prefill", "Executor.decode",
@@ -67,6 +71,8 @@ class Window:
     admitted: dict = dataclasses.field(default_factory=dict)   # rid -> s
     prefills: list = dataclasses.field(default_factory=list)   # [(rid, n)]
     steps: list = dataclasses.field(default_factory=list)      # [fills]
+    #: what each of ``Server.stats()``'s counters gained in the window
+    counters: dict = dataclasses.field(default_factory=dict)
     late: list = dataclasses.field(default_factory=list)
     failed: int = 0
 
@@ -90,7 +96,7 @@ class Run:
     """What a per-layer reader reads."""
 
     cell: Cell
-    model: counts.Model
+    model: object             # the family's counts of the cell's model
     window: Window
     trace: tracefile.Trace | None
     peaks: dict
@@ -138,9 +144,10 @@ def build(cell: Cell, seed: int):
     from repro.serve import ServeConfig, Server
 
     t0 = time.perf_counter()
-    bundle = ModelBundle(arch_config(cell.config))
+    bundle = ModelBundle(cell.family.arch_config(cell.config))
     shapes = jax.eval_shape(bundle.init_params, jax.random.PRNGKey(0))
-    params = jax.block_until_ready(weights.make_params(shapes, seed))
+    params = jax.block_until_ready(
+        weights.make_params(shapes, seed, cell.family.fan_in))
     t1 = time.perf_counter()
     s = cell.serve
     server = Server(
@@ -182,6 +189,11 @@ def warm(server, cell: Cell, seed: int) -> None:
 # the window
 # ---------------------------------------------------------------------------
 
+def counter_delta(before: dict, after: dict) -> dict:
+    """What each counter of ``Server.stats()`` gained between two reads."""
+    return {k: v - before[k] for k, v in after.items() if k in before}
+
+
 def serve(server, planned, seconds: float) -> Window:
     from repro.serve import QueueFullError, Request
 
@@ -212,6 +224,7 @@ def serve(server, planned, seconds: float) -> Window:
     eng.prefill, eng.decode = prefill, decode
     try:
         k, n = 0, len(planned)
+        before = server.stats()
         with span(tracefile.WINDOW_SPAN):
             win.t0 = now()
             t_end = win.t0 + seconds
@@ -239,6 +252,7 @@ def serve(server, planned, seconds: float) -> Window:
                     with span("wait_arrival"):
                         time.sleep(max(0.0, min(wake, t_end) - now()))
             win.t1, win.t_exit = t_end, now()
+        win.counters = counter_delta(before, server.stats())
     finally:
         del eng.prefill, eng.decode
     return win
@@ -323,14 +337,23 @@ def memory_peak_bytes(devices) -> int | None:
     return max(peaks) if peaks else None
 
 
+def traced(cell: Cell, win: Window, trace_dir: str, peaks) -> Run:
+    """What a per-layer reader reads: the window, the trace recorded in
+    ``trace_dir`` with the program's spans, and the family's counts."""
+    tr = tracefile.load(tracefile.find_xplane(trace_dir), HOST_SPANS,
+                        cell.chips)
+    return Run(cell, cell.family.counts(cell.model), win, tr, peaks)
+
+
 def compare(cell: Cell, params, win: Window, tap: check.Tap) -> dict:
     """The numbers compared, each with its limit."""
     lim = cell.config["correct"]
     done = finished(win)
     missing = sum(r.max_new_tokens - len(r.out_tokens) for r in done)
     watched = [r for r in win.sent if r.rid in tap.rids]
-    got = check.compare(params, cell.model, watched, tap.got,
-                        cell.serve["max_len"], cell.serve["prefill_chunk"])
+    got = check.compare(params, cell.family.forward, cell.model, watched,
+                        tap.got, cell.serve["max_len"],
+                        cell.serve["prefill_chunk"])
     log(f"check: {len(watched)} watched requests ({sum(r.done for r in watched)}"
         f" finished), {got['tokens']} served tokens and {got['dispatches']} "
         f"dispatches' logits against the float32 reference; fewest slots "
@@ -390,12 +413,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     out = {}
     breakdown = None
     if trace:
-        tr = tracefile.load(tracefile.find_xplane(trace_dir), HOST_SPANS,
-                            cell.chips)
+        ctx = traced(cell, win, trace_dir, peaks)
+        tr = ctx.trace
         if not keep_trace:
             shutil.rmtree(trace_dir, ignore_errors=True)
-        ctx = Run(cell, counts.Model.from_config(cell.model), win, tr,
-                  peaks)
         for m in cell.per_layer:
             value = metric_reader(m["name"])(ctx)
             if value is not None:

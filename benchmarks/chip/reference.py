@@ -1,4 +1,5 @@
-"""Plain float32 forward of a dense grouped-query decoder, layer by layer.
+"""Plain float32 forward of a dense grouped-query decoder, layer by layer,
+and the statistics of any family's reference logits that the check reads.
 
 Written from the layer equations, independent of the program: token
 embedding; per layer a pre-norm (OLMo's non-parametric LayerNorm or
@@ -18,6 +19,10 @@ below the served one (float8 for a bfloat16 model).  With
 ``scope="qkv"`` q, k and v are rounded to it before attention, as a
 float8 KV cache would hold them; with ``scope="all"`` both operands of
 every matmul are rounded too, as float8 weights and activations would be.
+
+``mm``, ``norm``, ``rope`` and ``head_weight`` are the parts another
+family's reference (``families/<family>.py``) builds its layers from;
+``position_stats`` takes any family's ``forward``.
 """
 
 from __future__ import annotations
@@ -32,14 +37,14 @@ HIGHEST = jax.lax.Precision.HIGHEST
 F32 = jnp.float32
 
 
-def _mm(spec, a, b, low=None):
+def mm(spec, a, b, low=None):
     a, b = a.astype(F32), b.astype(F32)
     if low is not None:
         a, b = (t.astype(low).astype(F32) for t in (a, b))
     return jnp.einsum(spec, a, b, precision=HIGHEST)
 
 
-def _norm(x, p, kind, eps):
+def norm(x, p, kind, eps):
     if kind == "rmsnorm":
         y = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
         return y * p["scale"].astype(F32)
@@ -49,7 +54,7 @@ def _norm(x, p, kind, eps):
     raise ValueError(f"unknown norm {kind!r}")
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """Rotate-half RoPE over (H, T, D) at positions 0..T-1."""
     half = x.shape[-1] // 2
     inv = theta ** (-jnp.arange(half, dtype=F32) / half)
@@ -65,11 +70,11 @@ def _layer(model, low, scope, stage, r, x):
     qkv_low, low = low, (low if scope == "all" else None)
     kind, eps = model["norm"], model["norm_eps"]
     at = lp["attn"]
-    h = _norm(x, lp["attn_norm"], kind, eps)
-    q = _mm("td,dhk->htk", h, at["w_q"], low)
-    k = _mm("td,dhk->htk", h, at["w_k"], low)
-    v = _mm("td,dhk->htk", h, at["w_v"], low)
-    q, k = _rope(q, model["rope_theta"]), _rope(k, model["rope_theta"])
+    h = norm(x, lp["attn_norm"], kind, eps)
+    q = mm("td,dhk->htk", h, at["w_q"], low)
+    k = mm("td,dhk->htk", h, at["w_k"], low)
+    v = mm("td,dhk->htk", h, at["w_v"], low)
+    q, k = rope(q, model["rope_theta"]), rope(k, model["rope_theta"])
     if qkv_low is not None:
         q, k, v = (t.astype(qkv_low).astype(F32) for t in (q, k, v))
     hkv, T, dh = k.shape
@@ -79,15 +84,15 @@ def _layer(model, low, scope, stage, r, x):
     s = jnp.where(causal, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("hgqk,hkd->hgqd", p, v, precision=HIGHEST)
-    x = x + _mm("htk,hkd->td", o.reshape(q.shape), at["w_o"], low)
-    h = _norm(x, lp["mlp_norm"], kind, eps)
+    x = x + mm("htk,hkd->td", o.reshape(q.shape), at["w_o"], low)
+    h = norm(x, lp["mlp_norm"], kind, eps)
     m = lp["mlp"]
-    g = jax.nn.silu(_mm("td,df->tf", h, m["w_gate"], low))
-    u = _mm("td,df->tf", h, m["w_up"], low)
-    return x + _mm("tf,fd->td", g * u, m["w_down"], low)
+    g = jax.nn.silu(mm("td,df->tf", h, m["w_gate"], low))
+    u = mm("td,df->tf", h, m["w_up"], low)
+    return x + mm("tf,fd->td", g * u, m["w_down"], low)
 
 
-def _head_w(params):
+def head_weight(params):
     head = params["head"]
     if "unembed" in head:
         return head["unembed"]
@@ -96,7 +101,7 @@ def _head_w(params):
 
 def _logits(model, low, final_norm, w, x):
     kind, eps = model["norm"], model["norm_eps"]
-    return _mm("td,dv->tv", _norm(x, final_norm, kind, eps), w, low)
+    return mm("td,dv->tv", norm(x, final_norm, kind, eps), w, low)
 
 
 def _stats(logits, targets):
@@ -142,8 +147,6 @@ def forward(params, model: dict, toks: np.ndarray, low=None,
             scope: str = "all") -> jax.Array:
     """(len(toks), vocab) float32 logits on the device, one layer at a
     time; ``low``/``scope`` as in the module's docstring."""
-    if set(k[1:] for s in params["stages"] for k in s) != {"F"}:
-        raise NotImplementedError("reference covers full-attention layers")
     layer, logits = _compiled(tuple(sorted(model.items())),
                               None if low is None else jnp.dtype(low).name,
                               scope)
@@ -152,13 +155,15 @@ def forward(params, model: dict, toks: np.ndarray, low=None,
         for sub in stage.values():
             for r in range(jax.tree.leaves(sub)[0].shape[0]):
                 x = layer(sub, jnp.int32(r), x)
-    return logits(params["final_norm"], _head_w(params), x)
+    return logits(params["final_norm"], head_weight(params), x)
 
 
-def position_stats(params, model: dict, seq: np.ndarray, length: int,
-                   pos=None, served=None, low=None, scope: str = "all"):
+def position_stats(params, forward, model: dict, seq: np.ndarray,
+                   length: int, pos=None, served=None, low=None,
+                   scope: str = "all"):
     """Reference statistics of ``seq`` (int tokens), padded to
-    ``length``: position t holds the logits that predict ``seq[t + 1]``.
+    ``length``: position t holds the logits that predict ``seq[t + 1]``
+    in the family's reference ``forward`` (``families/<family>.py``).
 
     Returns a dict of host arrays over the ``len(seq) - 1`` positions:
     ``best``, ``at`` (the logit of the next token), ``sumsq``, ``first``.

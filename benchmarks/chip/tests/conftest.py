@@ -20,6 +20,7 @@ def _tiny_config(arch: str) -> dict:
     a = c.attention
     return {
         "arch": arch,
+        "family": "dense",
         "model": {
             "num_hidden_layers": c.n_layers, "hidden_size": c.d_model,
             "intermediate_size": c.d_ff, "num_attention_heads": a.n_heads,
@@ -38,6 +39,19 @@ def _tiny_config(arch: str) -> dict:
 
 
 @pytest.fixture
+def no_compile_cache(monkeypatch):
+    """A run turns JAX's persistent cache on; keep this process's tests
+    off it, as they found it."""
+    import jax
+    from repro.launch import compile_cache
+
+    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: "off")
+    was = jax.config.jax_persistent_cache_min_compile_time_secs
+    yield
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", was)
+
+
+@pytest.fixture
 def tiny_config():
     return _tiny_config
 
@@ -46,7 +60,7 @@ def tiny_config():
 def tiny_cell():
     """A cell at smoke size that serves a few requests in a second or
     two on the CPU."""
-    from benchmarks.chip.cell import Cell
+    from benchmarks.chip.cell import Cell, load_family
 
     def make(arch="olmo-1b", kind="offline"):
         mix = {
@@ -54,7 +68,9 @@ def tiny_cell():
             "prompt": {"dist": "uniform", "lo": 4, "hi": 20},
             "output": {"dist": "uniform", "lo": 3, "hi": 8},
         }
-        return Cell(name="tiny", chips=1, config=_tiny_config(arch),
-                    traffic=mix, end_to_end=[], per_layer=[])
+        config = _tiny_config(arch)
+        return Cell(name="tiny", chips=1, config=config,
+                    family=load_family(config["family"]), traffic=mix,
+                    end_to_end=[], per_layer=[])
 
     return make

@@ -46,7 +46,8 @@ def test_config_entries_match_their_files(config):
     f = json.loads((ROOT / config["file"]).read_text())
     assert f["source"] == config["source"]
     assert f["reduced"] == config["reduced"]
-    assert {"model", "serve", "chips", "correct", "assumed"} <= set(f)
+    assert {"model", "family", "serve", "chips", "correct",
+            "assumed"} <= set(f)
 
 
 @pytest.mark.parametrize("metric", BENCH["per_layer"],
@@ -64,3 +65,6 @@ def test_no_stray_files():
     assert readers == {m["name"].split(".")[0] for m in BENCH["per_layer"]}
     mixes = {p.stem for p in (HERE / "traffic").glob("*.json")}
     assert mixes == {w["traffic"] for w in BENCH["workloads"]}
+    families = {p.stem for p in (HERE / "families").glob("*.py")}
+    assert families == {json.loads((ROOT / c["file"]).read_text())["family"]
+                        for c in BENCH["configs"]}

@@ -1,20 +1,26 @@
-"""Needed-work counts against hand-worked numbers for one layer of each
-config, and the peak table."""
+"""Needed-work counts, through each config's family, against hand-worked
+numbers for one layer of each config, and the peak table."""
 
 import json
 from pathlib import Path
 
 import pytest
 
-from benchmarks.chip.counts import Model, prompt_chunks
+from benchmarks.chip.cell import load_family
+from benchmarks.chip.counts import prompt_chunks
 from benchmarks.chip.peaks import peaks_for
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
 
 
+def counted(name, **changed):
+    config = json.loads((CONFIGS / f"{name}.json").read_text())
+    return load_family(config["family"]).counts(
+        dict(config["model"], **changed))
+
+
 def one_layer(name):
-    model = json.loads((CONFIGS / f"{name}.json").read_text())["model"]
-    return Model.from_config(dict(model, num_hidden_layers=1))
+    return counted(name, num_hidden_layers=1)
 
 
 def test_olmo_1b_one_layer():
@@ -41,10 +47,7 @@ def test_yi_6b_one_layer():
 
 
 def test_whole_models():
-    olmo = Model.from_config(
-        json.loads((CONFIGS / "olmo-1b.json").read_text())["model"])
-    yi = Model.from_config(
-        json.loads((CONFIGS / "yi-6b.json").read_text())["model"])
+    olmo, yi = counted("olmo-1b"), counted("yi-6b")
     assert olmo.decode_weight_bytes() == 2_353_528_832     # 2.35 GB
     assert yi.decode_weight_bytes() == 6_061_039_616       # 6.06 GB
     assert olmo.kv_bytes_per_position() == 128 * 1024

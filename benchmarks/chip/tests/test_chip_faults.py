@@ -14,6 +14,7 @@ from benchmarks.chip.cell import load_cell
 
 ROOT = Path(__file__).resolve().parents[3]
 SEED = 2**31 + 4242
+pytestmark = pytest.mark.usefixtures("no_compile_cache")
 
 
 def offline_metrics():
@@ -24,19 +25,6 @@ def offline_metrics():
 def run(cell):
     return harness.run(cell, SEED, 2.0, False, started=time.perf_counter(),
                        require_chip=False)
-
-
-@pytest.fixture(autouse=True)
-def no_compile_cache(monkeypatch):
-    """A run turns JAX's persistent cache on; keep this process's tests
-    off it, as they found it."""
-    import jax
-    from repro.launch import compile_cache
-
-    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: "off")
-    was = jax.config.jax_persistent_cache_min_compile_time_secs
-    yield
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", was)
 
 
 @pytest.fixture

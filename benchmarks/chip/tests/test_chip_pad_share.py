@@ -5,7 +5,7 @@ counters."""
 
 import pytest
 
-from benchmarks.chip import counts, harness, tracefile, traffic
+from benchmarks.chip import harness, tracefile, traffic
 from benchmarks.chip import program_trace as pt
 from benchmarks.chip.cell import metric_reader
 
@@ -17,8 +17,7 @@ def make_run(cell, prefills, modules, n_devices=1):
     win = harness.Window(prefills=prefills)
     tr = tracefile.Trace([("fusion.1", 0, 1)], modules, [], (0, 100),
                          n_devices)
-    return harness.Run(cell, counts.Model.from_config(cell.model), win, tr,
-                       None)
+    return harness.Run(cell, cell.family.counts(cell.model), win, tr, None)
 
 
 @pytest.fixture
@@ -54,9 +53,8 @@ def test_a_served_window_agrees_with_the_programs_counters(tiny_cell):
     params, server = harness.build(cell, SEED)
     planned = traffic.generate(cell.traffic, 2.0, SEED,
                                cell.model["vocab_size"])
-    before = server.stats()
     win = harness.serve(server, planned, 2.0)
-    got = pt.counter_delta(before, server.stats())
+    got = win.counters
     assert got["prefill_dispatches"] > 0
     assert got["prefill_tokens"] == sum(k for _, k in win.prefills)
     # the chip's trace holds one prefill executable run per dispatch

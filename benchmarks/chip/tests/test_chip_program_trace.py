@@ -6,8 +6,8 @@ span inside a harness span."""
 import jax
 import pytest
 
+from benchmarks.chip import harness, tracefile
 from benchmarks.chip import program_trace as pt
-from benchmarks.chip import tracefile
 
 D, F, P = pt.DISPATCH, pt.FETCH, pt.PREFILL
 
@@ -52,7 +52,7 @@ def test_pad_share_of_the_counters_over_the_window():
               "decode_steps": 4, "queued": 3}
     after = {"prefill_tokens": 213, "prefill_slot_tokens": 768,
              "decode_steps": 9, "queued": 0}
-    window = pt.counter_delta(before, after)
+    window = harness.counter_delta(before, after)
     assert window == {"prefill_tokens": 203, "prefill_slot_tokens": 512,
                       "decode_steps": 5, "queued": -3}
     assert pt.prefill_pad_share(window) == pytest.approx(
@@ -68,7 +68,7 @@ def test_no_counters_or_no_prefill_read_none():
 
 
 def test_load_keeps_only_the_programs_spans(tmp_path):
-    from jax.profiler import TraceAnnotation
+    from jax.profiler import ProfileData, TraceAnnotation
 
     with jax.profiler.trace(str(tmp_path)):
         with TraceAnnotation("Executor.decode"):
@@ -79,7 +79,9 @@ def test_load_keeps_only_the_programs_spans(tmp_path):
                 pass
         with TraceAnnotation("serve_not_the_program"):
             pass
-    spans = pt.load_spans(tracefile.find_xplane(str(tmp_path)))
+    data = ProfileData.from_file(tracefile.find_xplane(str(tmp_path)))
+    host, spans = tracefile.host_events(data, {"Executor.decode"})
+    assert [n for n, _, _ in host] == ["Executor.decode"]
     assert sorted(n for n, _, _ in spans) == sorted([D, F])
     (d,) = [x for x in spans if x[0] == D]
     (f,) = [x for x in spans if x[0] == F]

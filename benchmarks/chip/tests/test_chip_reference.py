@@ -7,18 +7,22 @@ import numpy as np
 import pytest
 
 from benchmarks.chip import check, reference, weights
-from benchmarks.chip.cell import arch_config
+from benchmarks.chip.cell import load_family
+
+dense = load_family("dense")
+forward = dense.forward
 
 
 def make_model(tiny_config, arch, dtype="float32"):
     config = tiny_config(arch)
     config["model"]["dtype"] = dtype
-    cfg = arch_config(config)
+    cfg = dense.arch_config(config)
     from repro.models.model_zoo import ModelBundle
 
     bundle = ModelBundle(cfg)
     shapes = jax.eval_shape(bundle.init_params, jax.random.PRNGKey(0))
-    return config["model"], cfg, weights.make_params(shapes, 2**33 + 5)
+    return (config["model"], cfg,
+            weights.make_params(shapes, 2**33 + 5, dense.fan_in))
 
 
 @pytest.mark.parametrize("arch", ["olmo-1b", "yi-6b"])
@@ -32,7 +36,8 @@ def test_reference_equals_the_programs_reference(tiny_config, arch):
         want = np.asarray(forward_logits(params, jnp.asarray(seq[None]),
                                          cfg))[0, :-1]
     pos = np.array([3, 17, 38], np.int32)
-    st = reference.position_stats(params, model, seq, 64, pos, want[pos])
+    st = reference.position_stats(params, forward, model, seq, 64, pos,
+                                  want[pos])
     np.testing.assert_allclose(st["best"], want.max(-1), rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(
         st["at"], want[np.arange(39), seq[1:]], rtol=1e-4, atol=1e-4)
@@ -54,11 +59,12 @@ def serve_exactly(params, model, prompt, n, length, chunk):
     seq = list(prompt)
     for _ in range(n):
         first = reference.position_stats(
-            params, model, np.asarray(seq + [0], np.int32), length)["first"]
+            params, forward, model, np.asarray(seq + [0], np.int32),
+            length)["first"]
         seq.append(int(first[len(seq) - 1]))
     toks = np.zeros(length, np.int32)
     toks[: len(seq)] = seq
-    logits = np.asarray(reference.forward(params, model, toks))
+    logits = np.asarray(forward(params, model, toks))
     pre, dec = check.expected_positions(len(prompt), n, chunk)
     taps = [("prefill", p, 4, logits[p]) for p in pre]
     taps += [("decode", p, 4, logits[p]) for p in dec]
@@ -76,7 +82,7 @@ def test_exact_tokens_pass_and_the_float8_control_fails(tiny_config, arch):
         out, got[rid] = serve_exactly(params, model, prompt, 24, 64, 8)
         rows.append(Served(rid, prompt, out))
     for scope in ("qkv", "all"):
-        res = check.compare(params, model, rows, got, 64, 8,
+        res = check.compare(params, forward, model, rows, got, 64, 8,
                             low=jnp.float8_e4m3fn, scope=scope)
         assert res["tokens"] == 96 and res["dispatches"] == 4 * (3 + 24)
         assert res["dispatch_mismatch"] == 0
@@ -86,11 +92,11 @@ def test_exact_tokens_pass_and_the_float8_control_fails(tiny_config, arch):
         assert any(res[f"low_{n}"] > limits[n] for n in check.NUMBERS), res
     # an altered token reads beyond the token limit
     rows[0].out_tokens[3] = (rows[0].out_tokens[3] + 1) % cfg.vocab
-    res = check.compare(params, model, rows, got, 64, 8)
+    res = check.compare(params, forward, model, rows, got, 64, 8)
     assert res["token_gap"] > 2 * limits["token_gap"]
     # a dispatch missing, or tapped twice, is a mismatch
     got[1] = got[1][:-1] + got[1][:1]
-    assert check.compare(params, model, rows, got, 64, 8)[
+    assert check.compare(params, forward, model, rows, got, 64, 8)[
         "dispatch_mismatch"] == 2
 
 

@@ -20,6 +20,8 @@ OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 #: the host span that brackets the measured window
 WINDOW_SPAN = "window"
+#: the program's own host spans all start so (``docs/serving.md``)
+PROGRAM_SPANS = "serve."
 
 
 @dataclasses.dataclass
@@ -29,6 +31,8 @@ class Trace:
     host: list         # [(name, start_ns, end_ns)] host spans
     window: tuple      # (start_ns, end_ns) of the measured window
     n_devices: int = 1
+    #: [(name, start_ns, end_ns)] the program's ``serve.*`` host spans
+    spans: list = dataclasses.field(default_factory=list)
 
     @property
     def window_s(self) -> float:
@@ -50,29 +54,49 @@ def _events(line):
             for e in line.events]
 
 
+def device_events(data, devices: int = 1):
+    """(ops, modules, lines seen) of the device planes ``/device:TPU:0
+    ..`` (the first ``devices`` of them) of a ``ProfileData``."""
+    ops, modules, seen = [], [], []
+    for plane in data.planes:
+        if not plane.name.startswith("/device:TPU:"):
+            continue
+        if int(plane.name.rsplit(":", 1)[1]) >= devices:
+            continue
+        for line in plane.lines:
+            seen.append(f"{plane.name}/{line.name}")
+            if line.name == OPS_LINE:
+                ops += _events(line)
+            elif line.name == MODULES_LINE:
+                modules += _events(line)
+    return ops, modules, seen
+
+
+def host_events(data, host_names):
+    """(host, spans) of a ``ProfileData``: the host events whose names
+    are in ``host_names``, and the program's (``PROGRAM_SPANS``)."""
+    host, spans = [], []
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in _events(line):
+                if e[0] in host_names:
+                    host.append(e)
+                elif e[0].startswith(PROGRAM_SPANS):
+                    spans.append(e)
+    return host, spans
+
+
 def load(path: str, host_names, devices: int = 1) -> Trace:
     """Device planes ``/device:TPU:0 ..`` (the first ``devices`` of
-    them), and the host spans whose names are in ``host_names``."""
+    them), the host spans whose names are in ``host_names``, and the
+    program's spans."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
-    ops, modules, host = [], [], []
-    seen = []
-    host_names = set(host_names) | {WINDOW_SPAN}
-    for plane in data.planes:
-        if plane.name.startswith("/device:TPU:"):
-            idx = int(plane.name.rsplit(":", 1)[1])
-            if idx >= devices:
-                continue
-            for line in plane.lines:
-                seen.append(f"{plane.name}/{line.name}")
-                if line.name == OPS_LINE:
-                    ops += _events(line)
-                elif line.name == MODULES_LINE:
-                    modules += _events(line)
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                host += [e for e in _events(line) if e[0] in host_names]
+    ops, modules, seen = device_events(data, devices)
+    host, spans = host_events(data, set(host_names) | {WINDOW_SPAN})
     windows = [e for e in host if e[0] == WINDOW_SPAN]
     if not ops or len(windows) != 1:
         raise ValueError(
@@ -80,7 +104,7 @@ def load(path: str, host_names, devices: int = 1) -> Trace:
             f"'{WINDOW_SPAN}' spans; device lines seen: {seen}"
         )
     host = [e for e in host if e[0] != WINDOW_SPAN]
-    return Trace(ops, modules, host, windows[0][1:], devices)
+    return Trace(ops, modules, host, windows[0][1:], devices, spans)
 
 
 def clip(events, lo: int, hi: int):

@@ -3,8 +3,8 @@
 The harness makes the weights itself, in the served dtype and in the
 layout the program's parameter tree has, and hands the same arrays to
 the program and (upcast) to the reference.  Initialisation is by each
-leaf's role, with fan-in scaling, so logits stay of order one and a
-comparison of logits means something.
+leaf's role, with fan-in scaling (the family's ``fan_in``), so logits
+stay of order one and a comparison of logits means something.
 """
 
 from __future__ import annotations
@@ -32,14 +32,7 @@ def _leaf_name(path) -> str:
     return str(getattr(path[-1], "key", path[-1]))
 
 
-def _fan_in(name: str, shape, stacked: bool) -> int:
-    dims = shape[1:] if stacked else shape
-    if name == "w_o":            # (heads, head_dim, d_model)
-        return dims[0] * dims[1]
-    return dims[0]
-
-
-def _init(path, s, key):
+def _init(path, s, key, fan_in):
     name = _leaf_name(path)
     if name in ONES:
         return jnp.ones(s.shape, s.dtype)
@@ -49,18 +42,21 @@ def _init(path, s, key):
         std = 0.02
     else:
         stacked = any(getattr(p, "key", None) == "stages" for p in path)
-        std = 1.0 / math.sqrt(_fan_in(name, s.shape, stacked))
+        std = 1.0 / math.sqrt(fan_in(name, s.shape, stacked))
     return (jax.random.normal(key, s.shape, s.dtype) * std).astype(s.dtype)
 
 
-def make_params(shapes, seed: int):
-    """``shapes``: the program's parameter tree as ShapeDtypeStructs."""
+def make_params(shapes, seed: int, fan_in):
+    """``shapes``: the program's parameter tree as ShapeDtypeStructs;
+    ``fan_in(name, shape, stacked)``: the family's fan-in of a weight
+    (``stacked`` when its first axis counts the layers of a stage)."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
 
     def build(key):
         keys = jax.random.split(key, len(flat))
         return jax.tree_util.tree_unflatten(
-            treedef, [_init(p, s, k) for (p, s), k in zip(flat, keys)]
+            treedef,
+            [_init(p, s, k, fan_in) for (p, s), k in zip(flat, keys)]
         )
 
     return jax.jit(build)(key_for(seed))
